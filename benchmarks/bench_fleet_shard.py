@@ -5,9 +5,11 @@ the cloud mesh").
 Sweep: fleet size B ∈ {4, 16, 64} × mesh {1, 2, 4, 8} virtual CPU devices
 (the `clients` axis of `launch.make_fleet_mesh`; mesh 1 is the unsharded
 baseline service). Every cell runs in its OWN subprocess with
-`--xla_force_host_platform_device_count=8` — XLA's device count is fixed at
-first import, so the parent bench process (which must keep seeing the single
-real device) cannot host the meshes itself.
+`JAX_PLATFORMS=cpu` and `--xla_force_host_platform_device_count=8` — XLA's
+device count is fixed at first import, so the parent bench process (which
+must keep seeing its own device) cannot host the meshes itself. These rows
+are CPU rehearsals; the sharded path on real chips is
+`chip_smoke.py --chips 4`.
 
 Reported per cell:
   * `us_per_call` — steady-state pooled sync wall time / B (per-client cost;
@@ -105,8 +107,12 @@ def run():
                       f"divide B={b} (replicate fallback)", flush=True)
                 continue
             payload = json.dumps({"B": b, "shards": d, "smoke": smoke})
+            # the children measure virtual-device partitioning on the CPU
+            # by design: never let one reach for an accelerator the parent
+            # process may hold
             out = subprocess.run([sys.executable, "-c", _SUBPROC, payload],
-                                 capture_output=True, text=True, timeout=1800)
+                                 capture_output=True, text=True, timeout=1800,
+                                 env={**os.environ, "JAX_PLATFORMS": "cpu"})
             if out.returncode != 0:
                 raise RuntimeError(
                     f"bench_fleet_shard B={b} mesh={d} failed:\n"

@@ -17,6 +17,7 @@ import sys
 import traceback
 
 from benchmarks import common
+from repro.launch.compile_cache import enable_compilation_cache
 
 MODULES = [
     "benchmarks.bench_memory",       # Figs. 2/6
@@ -70,6 +71,7 @@ def write_json(path: str, modules, failed) -> None:
 
 
 def main(argv=None) -> None:
+    enable_compilation_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--only", action="append", metavar="MODULE",
                     help="run only this module (repeatable; short name ok)")

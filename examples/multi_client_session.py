@@ -24,11 +24,13 @@ from repro.core.pipeline import SessionConfig
 from repro.core.video_model import (StreamConfig, nebula_bandwidth_bps,
                                     video_bandwidth_bps)
 from repro.serve.lod_service import LodService
+from repro.launch.compile_cache import enable_compilation_cache
 
 FOCAL = 260.0
 
 
 def main():
+    enable_compilation_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--clients", type=int, default=8)
     ap.add_argument("--syncs", type=int, default=24)

@@ -15,9 +15,11 @@ from repro.core.camera import StereoRig, make_camera
 from repro.core.gaussians import CityConfig, generate_city
 from repro.core.lod_tree import build_lod_tree
 from repro.core.pipeline import render_stereo, render_stereo_reference
+from repro.launch.compile_cache import enable_compilation_cache
 
 
 def main():
+    enable_compilation_cache()
     print("== building city scene ==")
     leaves = generate_city(CityConfig(blocks_x=3, blocks_y=3, leaf_density=0.2))
     tree = build_lod_tree(leaves, target_subtrees=32)

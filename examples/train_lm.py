@@ -18,9 +18,11 @@ from repro.models.config import reduced
 from repro.models.model_zoo import get_model
 from repro.train import optimizer as opt
 from repro.train.trainer import Trainer, TrainerConfig
+from repro.launch.compile_cache import enable_compilation_cache
 
 
 def main():
+    enable_compilation_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-3b", choices=sorted(ARCHS))
     ap.add_argument("--steps", type=int, default=300)

@@ -19,9 +19,11 @@ from repro.core.lod_tree import build_lod_tree
 from repro.core.pipeline import CollaborativeSession, SessionConfig
 from repro.core.video_model import (StreamConfig, nebula_bandwidth_bps,
                                     video_bandwidth_bps)
+from repro.launch.compile_cache import enable_compilation_cache
 
 
 def main():
+    enable_compilation_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--frames", type=int, default=96)
     ap.add_argument("--render-every", type=int, default=24)

@@ -92,10 +92,13 @@ def bytes_per_gaussian(sh_degree: int, raw: bool = True) -> int:
 
 
 def quat_to_rotmat(q: jax.Array) -> jax.Array:
-    """(…, 4) wxyz quaternion → (…, 3, 3) rotation matrix."""
-    q = q / (jnp.linalg.norm(q, axis=-1, keepdims=True) + 1e-12)
+    """(…, 4) wxyz quaternion → (…, 3, 3) rotation matrix. A numpy input
+    stays in numpy, so offline host code (the LoD tree build) dispatches
+    nothing to the accelerator."""
+    xp = np if isinstance(q, np.ndarray) else jnp
+    q = q / (xp.linalg.norm(q, axis=-1, keepdims=True) + 1e-12)
     w, x, y, z = q[..., 0], q[..., 1], q[..., 2], q[..., 3]
-    r = jnp.stack(
+    r = xp.stack(
         [
             1 - 2 * (y * y + z * z), 2 * (x * y - w * z), 2 * (x * z + w * y),
             2 * (x * y + w * z), 1 - 2 * (x * x + z * z), 2 * (y * z - w * x),

@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.lod_tree import LodTree
+from repro.core.lod_tree import LodTree, slab_subtree_end
 
 _EPS_DIST = 1e-6
 
@@ -118,6 +118,7 @@ class SlabTables:
     level: jax.Array     # (Ns, S) int32
     is_leaf: jax.Array   # (Ns, S) bool
     valid: jax.Array     # (Ns, S) bool
+    end: jax.Array       # (Ns, S) int32 — DFS subtree end (Pallas sweep)
 
     @staticmethod
     def from_tree(tree: LodTree, mesh=None) -> "SlabTables":
@@ -128,7 +129,8 @@ class SlabTables:
         tables = SlabTables(
             mu=tree.slab_mu(), size=tree.slab_size(),
             parent=tree.slab_parent, level=tree.slab_level,
-            is_leaf=tree.slab_is_leaf, valid=tree.slab_valid)
+            is_leaf=tree.slab_is_leaf, valid=tree.slab_valid,
+            end=jnp.asarray(slab_subtree_end(tree)))
         if mesh is not None:
             from repro.sharding.fleet import shard_slab_tables
             tables = shard_slab_tables(mesh, tables)
@@ -140,8 +142,23 @@ class SlabTables:
 # ---------------------------------------------------------------------------
 
 
-def _proj(size, dist, focal):
-    return size * focal / jnp.maximum(dist, _EPS_DIST)
+def sq_dist(mu, cam_pos):
+    """Squared distance over the last axis, summed x, y, z in that order:
+    an explicit sum rather than a reduction, so every sweep adds in the same
+    order whatever program it is fused into."""
+    d = mu - cam_pos
+    return d[..., 0] * d[..., 0] + d[..., 1] * d[..., 1] + d[..., 2] * d[..., 2]
+
+
+def lod_gt(size, dist2, focal, tau):
+    """proj(n) > τ, decided without a divide or a square root:
+    (size·focal)² > τ²·max(dist², ε²). Every sweep (XLA, the Pallas kernel,
+    the numpy reference) evaluates this one expression, so their decisions
+    agree bit for bit on any backend: a TPU's divide and square root may
+    round differently under XLA and under Mosaic, its multiplies do not."""
+    r = size * focal
+    t = jnp.asarray(tau, jnp.float32)
+    return r * r > (t * t) * jnp.maximum(dist2, _EPS_DIST * _EPS_DIST)
 
 
 def top_sweep(tree: LodTree, cam_pos: jax.Array, focal, tau
@@ -150,8 +167,7 @@ def top_sweep(tree: LodTree, cam_pos: jax.Array, focal, tau
     m = tree.meta
     mu = tree.top_mu()
     size = tree.top_size()
-    dist = jnp.linalg.norm(mu - cam_pos, axis=-1)
-    gt = _proj(size, dist, focal) > tau
+    gt = lod_gt(size, sq_dist(mu, cam_pos), focal, tau)
 
     expand = jnp.zeros((m.T,), bool)
     in_cut = jnp.zeros((m.T,), bool)
@@ -170,8 +186,8 @@ def top_sweep(tree: LodTree, cam_pos: jax.Array, focal, tau
 def _slab_sweep_one(mu, size, parent, level, is_leaf, valid, root_parent_expand,
                     cam_pos, focal, tau, max_depth: int):
     """Sweep a single (S,)-slab. Returns (in_cut, root_expand, rho)."""
-    dist = jnp.linalg.norm(mu - cam_pos, axis=-1)
-    gt = _proj(size, dist, focal) > tau
+    dist2 = sq_dist(mu, cam_pos)
+    gt = lod_gt(size, dist2, focal, tau)
 
     s = mu.shape[0]
     expand = jnp.zeros((s,), bool)
@@ -186,7 +202,10 @@ def _slab_sweep_one(mu, size, parent, level, is_leaf, valid, root_parent_expand,
     in_cut = pexp & (~gt | is_leaf) & valid
 
     # bit-accurate reuse bound: min distance-to-LoD-boundary over valid nodes
+    # (a radius, not a decision: it may differ from the Pallas sweep's in
+    # the last ulps)
     rstar = size * focal / tau
+    dist = jnp.linalg.norm(mu - cam_pos, axis=-1)
     margin = jnp.where(valid, jnp.abs(dist - rstar), jnp.inf)
     rho = jnp.min(margin)
     return in_cut, expand[0], rho
@@ -535,8 +554,11 @@ def reference_search_np(tree: LodTree, cam_pos, focal: float, tau: float
     is_leaf = np.concatenate([np.asarray(tree.top_is_leaf),
                               np.asarray(tree.slab_is_leaf).reshape(-1)])
 
-    dist = np.linalg.norm(mu - np.asarray(cam_pos, np.float32), axis=1)
-    gt = size * focal / np.maximum(dist, _EPS_DIST) > tau
+    d = mu - np.asarray(cam_pos, np.float32)
+    dist2 = d[:, 0] * d[:, 0] + d[:, 1] * d[:, 1] + d[:, 2] * d[:, 2]
+    r = size * np.float32(focal)
+    t = np.float32(tau)
+    gt = r * r > (t * t) * np.maximum(dist2, np.float32(_EPS_DIST * _EPS_DIST))
 
     n = mu.shape[0]
     expand = np.zeros(n, bool)

@@ -9,9 +9,10 @@ expanded).
 TPU-oriented layout (DESIGN.md §2):
   * the tree is partitioned offline at level P into `Ns` balanced subtrees;
   * the *top-tree* (levels < P) is small and laid out level-major;
-  * each subtree is a fixed-size *slab* of `S` nodes (BFS order inside the
-    slab, padded), so the per-frame sweep is a fully streaming, regular scan —
-    the TPU analogue of the paper's "blocks that fit in GPU shared memory";
+  * each subtree is a fixed-size *slab* of `S` nodes (DFS preorder inside
+    the slab, padded), so the per-frame sweep is a fully streaming, regular
+    scan — the TPU analogue of the paper's "blocks that fit in GPU shared
+    memory" — and every node's subtree is one contiguous range of its slab;
   * parent pointers inside a slab are slab-local (always a smaller index), and
     the slab root's parent lives in the top-tree — so a shard holding whole
     slabs never needs remote parents (cloud-side sharding, DESIGN.md §2).
@@ -192,7 +193,7 @@ def _merge_round(mu, log_scale, quat, opacity, sh, size, rng, b_lo, b_hi):
     p_mu = np.add.reduceat(w[:, None] * mu, starts) / sw[:, None]
 
     # covariance merge: Σ_p = Σ w (Σ_c + d dᵀ) / Σ w
-    rot = np.asarray(quat_to_rotmat(jnp.asarray(quat)))
+    rot = quat_to_rotmat(quat)
     sdiag = np.exp(log_scale)
     rs = rot * sdiag[:, None, :]
     cov = rs @ np.swapaxes(rs, 1, 2)
@@ -322,18 +323,33 @@ def build_lod_tree(
         sl = slice(offs[l], offs[l + 1])
         sub_of[sl] = sub_of[g_parent[sl]]
 
-    # slab-local BFS order: nodes of each subtree sorted by (level, global idx)
+    # slab-local DFS preorder: a node's subtree is the contiguous range
+    # [j, j + subtree size). Siblings are consecutive within their level
+    # (merge groups are runs), so preorder positions follow level by level
+    # from the parent's position plus the sizes of the earlier siblings.
+    sub_size = np.ones(n_real, np.int64)
+    for l in range(depth, P, -1):
+        sl = slice(offs[l], offs[l + 1])
+        sub_size[offs[l - 1]:offs[l]] += np.bincount(
+            g_parent[sl] - offs[l - 1], weights=sub_size[sl],
+            minlength=offs[l] - offs[l - 1]).astype(np.int64)
+    preorder = np.zeros(n_real, np.int64)
+    for l in range(P + 1, depth + 1):
+        sl = slice(offs[l], offs[l + 1])
+        par = g_parent[sl]
+        csum = np.cumsum(sub_size[sl])
+        first = np.searchsorted(par, par)          # first sibling's index
+        before = csum - sub_size[sl] - np.where(first > 0, csum[first - 1], 0)
+        preorder[sl] = preorder[par] + 1 + before
+
     members = np.where(sub_of >= 0)[0]
-    order2 = np.lexsort((members, g_level[members], sub_of[members]))
-    members = members[order2]
     sub_sorted = sub_of[members]
-    sub_starts = np.searchsorted(sub_sorted, np.arange(Ns))
-    sub_counts = np.searchsorted(sub_sorted, np.arange(Ns) + 1) - sub_starts
+    sub_counts = np.bincount(sub_sorted, minlength=Ns)
     S_raw = int(sub_counts.max()) if Ns else 1
     S = int(np.ceil(S_raw / slab_pad_to) * slab_pad_to)
 
     # local index of each member node within its slab
-    local_idx = np.arange(len(members)) - sub_starts[sub_sorted]
+    local_idx = preorder[members]
     loc_of_global = np.full(n_real, -1, np.int64)
     loc_of_global[members] = local_idx
 
@@ -414,3 +430,44 @@ def build_lod_tree(
         slab_root_parent_top=jnp.asarray(root_parent_top),
         meta=meta,
     )
+
+
+def slab_subtree_end(tree: LodTree) -> np.ndarray:
+    """(Ns, S) int32 — for every slab node j, one past the last node of its
+    subtree, so the subtree is the slab range [j, end[j]) (0 on padding).
+
+    The Pallas slab sweep (repro.kernels.lod_cut) reads ancestry from these
+    ranges instead of gathering parents, which holds only for DFS-preorder
+    slabs — the layout `build_lod_tree` emits. The ranges are checked here:
+    every node lies inside its parent's range, and the ranges covering a
+    node are exactly its ancestors (their count equals its level)."""
+    parent = np.asarray(tree.slab_parent).astype(np.int64)
+    level = np.asarray(tree.slab_level).astype(np.int64)
+    valid = np.asarray(tree.slab_valid)
+    ns, s = parent.shape
+    size = valid.astype(np.int64)
+    base = (np.arange(ns) * s)[:, None]
+    for l in range(tree.meta.slab_max_depth, 0, -1):
+        at = valid & (level == l)
+        size += np.bincount((base + parent)[at], weights=size[at],
+                            minlength=ns * s).reshape(ns, s).astype(np.int64)
+    pos = np.arange(s)[None, :]
+    end = np.where(valid, pos + size, 0)
+    # ranges covering each node: +1 just after a range's root, -1 at its end
+    row = (np.arange(ns) * (s + 1))[:, None]
+    marks = ns * (s + 1)
+    cover = (np.bincount((row + pos + 1)[valid], minlength=marks)
+             - np.bincount((row + end)[valid], minlength=marks))
+    cover = np.cumsum(cover.reshape(ns, s + 1), axis=1)[:, :s]
+    child = valid & (parent >= 0)
+    p = np.clip(parent, 0, s - 1)
+    p_end = np.take_along_axis(end, p, axis=1)
+    p_level = np.take_along_axis(level, p, axis=1)
+    ok = (np.all(~valid | (cover == level))
+          and np.all(valid[:, 0] == (level[:, 0] == 0))
+          and np.all(~child | ((p < pos) & (end <= p_end)
+                               & (level == p_level + 1))))
+    if not ok:
+        raise ValueError("slabs are not in DFS preorder; rebuild the tree "
+                         "with build_lod_tree")
+    return end.astype(np.int32)

@@ -156,6 +156,20 @@ def batched_cloud_sync(states: ManagerState, cut_masks: jax.Array,
         states, cut_masks, ts, w_star)
 
 
+def _pairwise_sum(x: jax.Array) -> jax.Array:
+    """Sum over the last axis by halving it: elementwise adds in one fixed
+    order whatever the leading axes' layout, so a fleet sharded over clients
+    sums each client's floats exactly as one device does (a reduce may pick
+    its order per program; on a TPU it did, per-device shape by shape)."""
+    n = x.shape[-1]
+    width = 1 << max(n - 1, 0).bit_length()
+    x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - n)])
+    while x.shape[-1] > 1:
+        half = x.shape[-1] // 2
+        x = x[..., :half] + x[..., half:]
+    return x[..., 0]
+
+
 def batched_wire_bytes(plan: SyncPlan, bytes_per_gaussian: float, *,
                        shared_payload: bool = False,
                        active=None, delivered=None,
@@ -205,8 +219,9 @@ def batched_wire_bytes(plan: SyncPlan, bytes_per_gaussian: float, *,
         out = plan.n_delta.astype(jnp.float32) * bytes_per_gaussian + base
     else:
         share = delta.sum(axis=0)                            # (N,) requesters
-        frac = jnp.where(delta,
-                         1.0 / jnp.maximum(share, 1)[None, :], 0.0).sum(axis=1)
+        frac = _pairwise_sum(jnp.where(delta,
+                                       1.0 / jnp.maximum(share, 1)[None, :],
+                                       0.0))
         out = frac * (bytes_per_gaussian + ID_BYTES_DELTA) + base
         if client_pages is not None:
             out = out + client_pages.astype(jnp.float32) * PAGE_HEADER_BYTES

@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 _NEG_INF = -1e30
 
 
@@ -59,7 +61,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q: int, block_k: int,
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool = True, window: int = 0,
                            block_q: int = 128, block_k: int = 128,
-                           interpret: bool = True) -> jax.Array:
+                           interpret=None) -> jax.Array:
     """q: (B, H, Lq, D); k, v: (B, Hkv, Lk, D) with H % Hkv == 0."""
     b, h, lq, d = q.shape
     hkv, lk = k.shape[1], k.shape[2]
@@ -81,5 +83,5 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, d), lambda bb, hh, qq: (bb, hh, qq, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, lq, d), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

@@ -1,10 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Every op takes `use_pallas` (+ `interpret`); the fallback is the pure-jnp
-oracle path, so callers can flip between the accelerator kernel and XLA. On
-this CPU container the kernels run with interpret=True; on TPU the same call
-sites compile the real kernels (the dry-run deliberately uses the jnp paths —
-see DESIGN.md §7)."""
+Every op takes `use_pallas`; the fallback is the pure-jnp oracle path, so
+callers can flip between the accelerator kernel and XLA. `interpret=None`
+lets the backend decide (`repro.kernels.resolve_interpret`): the kernels
+compile on a TPU and run in the Pallas interpreter on the CPU."""
 
 from __future__ import annotations
 
@@ -16,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.binning import TileLists
+from repro.core.lod_tree import slab_subtree_end
 from repro.core.projection import Splats
 from repro.render.common import eye_views
 from repro.kernels import ref as kref
@@ -48,7 +48,7 @@ def gather_entries(lists: TileLists, s: Splats, eye: str
 
 def rasterize(lists: TileLists, s: Splats, *, width: int, height: int,
               tile: int, eye: str, eps_t: float = 0.0, use_pallas: bool = True,
-              interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+              interpret=None) -> Tuple[jax.Array, jax.Array]:
     """Tile raster → (image (H, W, 3), α-hit flags (n_tiles, L))."""
     entries, counts = gather_entries(lists, s, eye)
     if use_pallas:
@@ -68,7 +68,7 @@ def rasterize(lists: TileLists, s: Splats, *, width: int, height: int,
 
 
 def vq_assign(x: jax.Array, codebook: jax.Array, *, use_pallas: bool = True,
-              interpret: bool = True) -> jax.Array:
+              interpret=None) -> jax.Array:
     if use_pallas:
         return vq_assign_pallas(x, codebook, interpret=interpret)
     return kref.ref_vq_assign(x, codebook)
@@ -78,7 +78,7 @@ def vq_assign(x: jax.Array, codebook: jax.Array, *, use_pallas: bool = True,
 
 
 def preprocess(g, rig, wide, *, use_pallas: bool = True,
-               interpret: bool = True) -> Splats:
+               interpret=None) -> Splats:
     """Kernelized repro.core.projection.project (same Splats output)."""
     if not use_pallas:
         from repro.core.projection import project
@@ -96,13 +96,15 @@ def preprocess(g, rig, wide, *, use_pallas: bool = True,
 
 
 def lod_slab_sweep(tree, cam_pos, focal, tau, root_parent_expand, *,
-                   use_pallas: bool = True, interpret: bool = True):
+                   use_pallas: bool = True, interpret=None):
+    if use_pallas:
+        return lod_slab_sweep_pallas(
+            tree.slab_mu(), tree.slab_size(),
+            jnp.asarray(slab_subtree_end(tree)), tree.slab_is_leaf,
+            tree.slab_valid, root_parent_expand, cam_pos, focal, tau,
+            interpret=interpret)
     args = (tree.slab_mu(), tree.slab_size(), tree.slab_parent, tree.slab_level,
             tree.slab_is_leaf, tree.slab_valid, root_parent_expand)
-    if use_pallas:
-        return lod_slab_sweep_pallas(*args, cam_pos, focal, tau,
-                                     max_depth=tree.meta.slab_max_depth,
-                                     interpret=interpret)
     return kref.ref_lod_slab_sweep(*args, cam_pos, focal, tau,
                                    max_depth=tree.meta.slab_max_depth)
 
@@ -156,7 +158,7 @@ def build_merge_sources(left: TileLists, s: Splats, ranks: jax.Array, *,
 
 def stereo_merge(left: TileLists, s: Splats, ranks: jax.Array, *, tile: int,
                  width: int, n_cat: int, use_pallas: bool = True,
-                 interpret: bool = True) -> TileLists:
+                 interpret=None) -> TileLists:
     """Kernelized stereo.stereo_lists (same TileLists output)."""
     src_ranks, src_ids = build_merge_sources(left, s, ranks, tile=tile,
                                              width=width, n_cat=n_cat)
@@ -178,7 +180,7 @@ def stereo_merge(left: TileLists, s: Splats, ranks: jax.Array, *, tile: int,
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    use_pallas: bool = True, interpret: bool = True):
+                    use_pallas: bool = True, interpret=None):
     if use_pallas:
         return flash_attention_pallas(q, k, v, causal=causal, window=window,
                                       interpret=interpret)

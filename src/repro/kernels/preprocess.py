@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 from repro.core.gaussians import SH_C0, SH_C1
 from repro.core.projection import ALPHA_MIN, COV_BLUR
 
@@ -138,9 +140,17 @@ OUT_COLS = 17
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def preprocess_pallas(mu, log_scale, quat, opacity, sh, cam_params, *,
-                      block: int = 256, interpret: bool = True) -> jax.Array:
+                      block: int = 256, interpret=None) -> jax.Array:
     """Returns (M, 17): [mean2d(2), depth, conic(3), ext(2), color_l(3),
-    color_r(3), opacity, disparity, visible]."""
+    color_r(3), opacity, disparity, visible].
+
+    Interpret-only: the (B, 3)-wide blocks and per-Gaussian 3×3 matmuls do
+    not lower to Mosaic, so a compiled call (a TPU backend, or
+    `interpret=False`) raises instead of silently interpreting — use the
+    XLA projection (`ops.preprocess(use_pallas=False)`) there."""
+    if not resolve_interpret(interpret):
+        raise NotImplementedError(
+            "preprocess_pallas does not lower to Mosaic; use the XLA projection")
     m = mu.shape[0]
     sh_k = sh.shape[1]
     block = min(block, m)
@@ -159,5 +169,5 @@ def preprocess_pallas(mu, log_scale, quat, opacity, sh, cam_params, *,
         ],
         out_specs=pl.BlockSpec((block, OUT_COLS), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, OUT_COLS), jnp.float32),
-        interpret=interpret,
+        interpret=True,
     )(cam_params, mu, log_scale, quat, opacity, sh.reshape(m, sh_k * 3))

@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 _INF = 2**30  # plain literal — jnp constants would be captured as consts
 
 
@@ -55,7 +57,7 @@ def _merge_kernel(ranks_ref, ids_ref, out_ref, cnt_ref, ovf_ref, *, n_cat: int,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def stereo_merge_pallas(src_ranks: jax.Array, src_ids: jax.Array, *,
-                        interpret: bool = True):
+                        interpret=None):
     """src_ranks/src_ids: (n_tiles, n_cat, L) — per right tile, the n_cat
     include-filtered sorted source rows (INF/-1 padded).
     Returns (merged ids (n_tiles, L), counts (n_tiles,), overflow (n_tiles,)).
@@ -64,7 +66,15 @@ def stereo_merge_pallas(src_ranks: jax.Array, src_ids: jax.Array, *,
     output capacity — the write loop drops the tail, so a True flag means
     tile t's list is TRUNCATED (counts still reports the untruncated total;
     callers surface the flag on the merged TileLists instead of silently
-    clamping)."""
+    clamping).
+
+    Interpret-only: the per-row head gathers span several vregs, which
+    Mosaic cannot lower, so a compiled call (a TPU backend, or
+    `interpret=False`) raises instead of silently interpreting — use the
+    XLA merge (`ops.stereo_merge(use_pallas=False)`) there."""
+    if not resolve_interpret(interpret):
+        raise NotImplementedError(
+            "stereo_merge_pallas does not lower to Mosaic; use the XLA merge")
     n_tiles, n_cat, l_len = src_ranks.shape
     kernel = functools.partial(_merge_kernel, n_cat=n_cat, l_len=l_len,
                                out_len=l_len)
@@ -85,5 +95,5 @@ def stereo_merge_pallas(src_ranks: jax.Array, src_ids: jax.Array, *,
             jax.ShapeDtypeStruct((n_tiles,), jnp.int32),
             jax.ShapeDtypeStruct((n_tiles,), jnp.bool_),
         ],
-        interpret=interpret,
+        interpret=True,
     )(src_ranks, src_ids)

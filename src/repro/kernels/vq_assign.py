@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 
 def _vq_kernel(x_ref, cb_ref, c2_ref, val_ref, idx_ref, *, block_k: int):
     kb = pl.program_id(1)
@@ -39,7 +41,7 @@ def _vq_kernel(x_ref, cb_ref, c2_ref, val_ref, idx_ref, *, block_k: int):
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_k", "interpret"))
 def vq_assign_pallas(x: jax.Array, codebook: jax.Array, *, block_m: int = 256,
-                     block_k: int = 128, interpret: bool = True) -> jax.Array:
+                     block_k: int = 128, interpret=None) -> jax.Array:
     """(M, D) × (Kc, D) → (M,) nearest codeword indices."""
     m, d = x.shape
     kc = codebook.shape[0]
@@ -64,7 +66,7 @@ def vq_assign_pallas(x: jax.Array, codebook: jax.Array, *, block_m: int = 256,
             jax.ShapeDtypeStruct((m,), jnp.float32),
             jax.ShapeDtypeStruct((m,), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, codebook, c2)
     del val
     return idx

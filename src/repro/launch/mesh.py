@@ -10,24 +10,14 @@ jax device state). Axis semantics:
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes) -> jax.sharding.Mesh:
-    """Version-tolerant mesh construction.
-
-    Newer JAX exposes `jax.sharding.AxisType` and `jax.make_mesh(...,
-    axis_types=...)`; older releases (e.g. 0.4.x) have neither. All our
-    axes are Auto (the compiler is free to pick collectives), which is also
-    the default when the parameter does not exist."""
-    shape, axes = tuple(shape), tuple(axes)
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:
-            pass  # make_mesh predates the axis_types kwarg
-    return jax.make_mesh(shape, axes)
+    """All our axes are Auto: the compiler is free to pick collectives."""
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

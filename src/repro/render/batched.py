@@ -78,8 +78,7 @@ def _vmapped_frames_jit(queues, rigs, cfg):
 
 def batched_render_stereo(queues: Gaussians, rigs: StereoRig,
                           cfg: RenderConfig, *, path: str = "vmap",
-                          jit: bool = False, interpret: bool = True,
-                          active=None, mesh=None
+                          jit: bool = False, active=None, mesh=None
                           ) -> Tuple[jax.Array, jax.Array, StereoFrameStats]:
     """Render B clients → (img_l (B,H,W,3), img_r (B,H,W,3), per-client
     StereoFrameStats). `queues`/`rigs` carry a leading client axis (see
@@ -111,8 +110,7 @@ def batched_render_stereo(queues: Gaussians, rigs: StereoRig,
         return _constrain_frames(out, mesh)
     if path == "pooled":
         return _constrain_frames(
-            _pooled_render(queues, rigs, cfg, interpret=interpret,
-                           active=active, mesh=mesh), mesh)
+            _pooled_render(queues, rigs, cfg, active=active, mesh=mesh), mesh)
     raise ValueError(f"unknown batched render path: {path!r}")
 
 
@@ -180,8 +178,8 @@ def _assemble(tiles_img, tiles_y, tiles_x, tile, height, width):
     return img[:, :height, :width]
 
 
-def _pooled_render(queues, rigs, cfg: RenderConfig, *, interpret: bool = True,
-                   active=None, mesh=None):
+def _pooled_render(queues, rigs, cfg: RenderConfig, *, active=None,
+                   mesh=None):
     from repro.kernels.rasterize import rasterize_slabs_pallas
 
     plans = batched_build_plans(queues, rigs, cfg)
@@ -213,7 +211,7 @@ def _pooled_render(queues, rigs, cfg: RenderConfig, *, interpret: bool = True,
         sel = jnp.asarray(np.resize(occupied, bucket))
         tiles_img, hits = rasterize_slabs_pallas(
             entries[sel], counts[sel], origins[sel], tile=cfg.tile,
-            eps_t=cfg.eps_t, interpret=interpret)
+            eps_t=cfg.eps_t)
         all_img, all_hits = _scatter_slabs(
             sel, tiles_img, hits, n_slabs=n_slabs, tile=cfg.tile,
             l_len=cfg.list_len)

@@ -133,32 +133,27 @@ def bin_shared(splats: Splats, ranks: jax.Array, cfg: RenderConfig
 
 
 def stereo_merge(splats: Splats, ranks: jax.Array, left: TileLists,
-                 cfg: RenderConfig, *, use_pallas: bool = False,
-                 interpret: bool = True) -> TileLists:
+                 cfg: RenderConfig, *, use_pallas: bool = False) -> TileLists:
     """Right-eye lists via the SRU/line-buffer k-way shift-merge (no re-sort,
     no re-bin). `use_pallas` switches to the merge kernel (same output)."""
     if use_pallas:
         from repro.kernels import ops as kops
         return kops.stereo_merge(left, splats, ranks, tile=cfg.tile,
-                                 width=cfg.width, n_cat=cfg.n_cat,
-                                 interpret=interpret)
+                                 width=cfg.width, n_cat=cfg.n_cat)
     return stereo_lists(left, splats, ranks, tile=cfg.tile, width=cfg.width,
                         n_cat=cfg.n_cat)
 
 
 def build_plan(queue: Gaussians, rig: StereoRig, cfg: RenderConfig, *,
-               use_pallas_merge: bool = False, interpret: bool = True
-               ) -> RenderPlan:
+               use_pallas_merge: bool = False) -> RenderPlan:
     """project → bin_shared → stereo_merge, composed."""
     splats, ranks = project(queue, rig, cfg)
     left = bin_shared(splats, ranks, cfg)
-    right = stereo_merge(splats, ranks, left, cfg,
-                         use_pallas=use_pallas_merge, interpret=interpret)
+    right = stereo_merge(splats, ranks, left, cfg, use_pallas=use_pallas_merge)
     return RenderPlan(splats=splats, ranks=ranks, left=left, right=right)
 
 
-def rasterize(plan: RenderPlan, cfg: RenderConfig, *, use_pallas: bool = False,
-              interpret: bool = True
+def rasterize(plan: RenderPlan, cfg: RenderConfig, *, use_pallas: bool = False
               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Rasterize both eyes from a plan → (img_l, img_r, left α-hit flags).
 
@@ -172,12 +167,10 @@ def rasterize(plan: RenderPlan, cfg: RenderConfig, *, use_pallas: bool = False,
         from repro.kernels import ops as kops
         img_l, hits = kops.rasterize(plan.left, plan.splats, width=cfg.width,
                                      height=cfg.height, tile=cfg.tile,
-                                     eye="left", eps_t=cfg.eps_t,
-                                     interpret=interpret)
+                                     eye="left", eps_t=cfg.eps_t)
         img_r, _ = kops.rasterize(plan.right, plan.splats, width=cfg.width,
                                   height=cfg.height, tile=cfg.tile,
-                                  eye="right", eps_t=cfg.eps_t,
-                                  interpret=interpret)
+                                  eye="right", eps_t=cfg.eps_t)
         return img_l, img_r, hits
     img_l, hits = render_tiles(plan.left, plan.splats, width=cfg.width,
                                height=cfg.height, tile=cfg.tile, eye="left",
@@ -190,10 +183,10 @@ def rasterize(plan: RenderPlan, cfg: RenderConfig, *, use_pallas: bool = False,
 
 
 def render_stereo(plan: RenderPlan, cfg: RenderConfig, *,
-                  use_pallas: bool = False, interpret: bool = True
+                  use_pallas: bool = False
                   ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One call from plan to pixels: (img_l, img_r, left α-hit flags)."""
-    return rasterize(plan, cfg, use_pallas=use_pallas, interpret=interpret)
+    return rasterize(plan, cfg, use_pallas=use_pallas)
 
 
 def render_stereo_reference(queue: Gaussians, rig: StereoRig,

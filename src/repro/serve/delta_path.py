@@ -119,36 +119,61 @@ class DeltaBatch:
         return self.ref_mask.shape[0]
 
 
+_PRIO_PAD = 2**31 - 1  # non-members sort after every real row
+
+
 @jax.jit
-def _union_mask(delta_masks: jax.Array):
-    union = jnp.any(delta_masks, axis=0)               # (N,)
-    return union, union.sum().astype(jnp.int32)
+def _union_mask(wanted: jax.Array, priority: jax.Array):
+    """The size of the sync's union and every row's rank key: one int32
+    ordering rows by (priority asc, requester count desc), non-members
+    last. Priorities are non-negative; those above ~2^31/(B+1) tie (tree
+    levels stay ordered, and padding's level sentinel still sorts last)."""
+    b = wanted.shape[0]
+    union = jnp.any(wanted, axis=0)                    # (N,)
+    req = wanted.sum(axis=0).astype(jnp.int32)         # 0..B
+    prio = jnp.clip(priority.astype(jnp.int32), 0, (_PRIO_PAD - 1 - b) // (b + 1))
+    key = jnp.where(union, prio * (b + 1) + (b - req), jnp.int32(_PRIO_PAD))
+    return union.sum().astype(jnp.int32), key
 
 
-_PRIO_PAD = jnp.int32(2**31 - 1)  # non-members sort after every real row
+@jax.jit
+def _rank_union(key: jax.Array, n_union: jax.Array, width: jax.Array):
+    """Rank every row by its key, ties by gid (a stable sort of the gids),
+    and lay the top min(n_union, width) ranks out in wire order (ascending
+    gid). Width is traced: the N-row sort compiles once per table size, not
+    once per pow2 stream width (on a TPU that compile takes tens of seconds
+    at city scale). Returns (gids by rank, rank of each gid, shipped gids
+    ascending then -1), all (N,)."""
+    n = key.shape[0]
+    gid = jnp.arange(n, dtype=jnp.int32)
+    _, by_rank = jax.lax.sort((key, gid), num_keys=1, is_stable=True)
+    rank_of = jnp.zeros((n,), jnp.int32).at[by_rank].set(gid)
+    shipped = rank_of < jnp.minimum(n_union, width)
+    pos = jnp.cumsum(shipped.astype(jnp.int32)) - 1
+    wire = jnp.full((n,), -1, jnp.int32).at[jnp.where(shipped, pos, n)].set(
+        gid, mode="drop")
+    return by_rank, rank_of, wire
 
 
 @functools.partial(jax.jit, static_argnames=("width", "page_size", "mesh"))
-def _union_refs(wanted: jax.Array, union: jax.Array, priority: jax.Array,
-                allowance: jax.Array, width: int, page_size: int, mesh=None):
+def _union_refs(wanted: jax.Array, by_rank: jax.Array, rank_of: jax.Array,
+                wire: jax.Array, n_union: jax.Array, allowance: jax.Array,
+                width: int, page_size: int, mesh=None):
     """Priority-ordered page selection of one sync's union.
 
-    Ranks every union row by (tree depth asc, requester count desc, gid asc)
-    — coarse LoD ships first, ties broken toward the most-shared rows — and
-    ships the top `width` ranks. The stream itself stays ASCENDING by gid
-    (delta-coded ids; each page is internally ascending), so the shipped
-    subset decodes exactly like the unpaged format. `allowance` (B,) caps
-    the rows each client ingests this sync, counted in priority order, so a
-    bandwidth-tiered client takes the coarsest pages first and defers the
-    rest. Returns everything the batch needs: the wire-order gids/refs, the
-    node-indexed delivered/deferred masks, and the page accounting."""
+    Union rows are ranked (`_rank_union`) by (tree depth asc, requester
+    count desc, gid asc) — coarse LoD ships first, ties broken toward the
+    most-shared rows — and the top `width` ranks ship. The stream itself
+    stays ASCENDING by gid (delta-coded ids; each page is internally
+    ascending), so the shipped subset decodes exactly like the unpaged
+    format. `allowance` (B,) caps the rows each client ingests this sync,
+    counted in priority order, so a bandwidth-tiered client takes the
+    coarsest pages first and defers the rest. Returns everything the batch
+    needs: the wire-order gids/refs, the node-indexed delivered/deferred
+    masks, and the page accounting."""
     b, n = wanted.shape
-    gid = jnp.arange(n, dtype=jnp.int32)
-    req = wanted.sum(axis=0).astype(jnp.int32)
-    k1 = jnp.where(union, priority.astype(jnp.int32), _PRIO_PAD)
-    k1s, _, by_rank = jax.lax.sort((k1, -req, gid), num_keys=3)
     take = by_rank[:width]                       # gids, priority order
-    valid = k1s[:width] != _PRIO_PAD             # rank is a real union row
+    valid = jnp.arange(width) < n_union          # rank is a real union row
     n_shipped = valid.sum().astype(jnp.int32)
 
     # per-client ingest: its wanted rows among the shipped ranks, first
@@ -169,11 +194,11 @@ def _union_refs(wanted: jax.Array, union: jax.Array, priority: jax.Array,
     deferred = wanted & ~delivered
     client_overflow = deferred.any(axis=1)
 
-    # wire order: shipped gids ascending (invalid ranks sort last, pad -1)
-    order = jnp.argsort(jnp.where(valid, take, jnp.int32(n)))
-    gids = jnp.where(valid[order], take[order], -1).astype(jnp.int32)
-    ref = ingest[:, order]
-    row_page = jnp.where(valid[order], page_of[order], -1).astype(jnp.int32)
+    # wire order: shipped gids ascending, pad -1
+    gids = wire[:width]
+    on_wire = gids >= 0
+    ref = delivered[:, gids] & on_wire[None, :]
+    row_page = jnp.where(on_wire, rank_of[gids] // page_size, -1)
     if mesh is not None:
         from repro.sharding.fleet import constrain_fleet
         # the union row axis shards over `slabs` (codec work parallelism);
@@ -235,19 +260,19 @@ def build_delta_batch(gaussians: Gaussians, codec: comp.Codec,
         if pending is not None:
             pending = pending & active[:, None]
     wanted = delta_masks if pending is None else delta_masks | pending
-    union, n_union = _union_mask(wanted)
-    n = int(jax.device_get(n_union))
-    width = ls.pow2_bucket(n, budget)
-    b = wanted.shape[0]
+    b, n_rows = wanted.shape
     if priority is None:
-        priority = jnp.zeros((wanted.shape[1],), jnp.int32)
+        priority = jnp.zeros((n_rows,), jnp.int32)
+    n_union, key = _union_mask(wanted, priority)
+    width = ls.pow2_bucket(int(jax.device_get(n_union)), budget)
     allow = (jnp.full((b,), width, jnp.int32) if allowance is None
              else jnp.asarray(allowance, jnp.int32))
     psize = width if page_size is None else max(1, min(int(page_size), width))
+    by_rank, rank_of, wire = _rank_union(key, n_union, jnp.int32(width))
     (gids, ref, delivered, deferred, client_overflow, client_pages, pages,
-     n_shipped, row_page) = _union_refs(wanted, union, priority, allow,
-                                        width=width, page_size=psize,
-                                        mesh=mesh)
+     n_shipped, row_page) = _union_refs(wanted, by_rank, rank_of, wire,
+                                        n_union, allow, width=width,
+                                        page_size=psize, mesh=mesh)
     payload = comp.encode_rows(codec, gaussians, gids)
     if mesh is not None:
         from repro.sharding.fleet import constrain_fleet
@@ -269,8 +294,17 @@ def decode_client(codec: comp.Codec, batch: DeltaBatch, sh_k: int,
     not referenced — and the decoded union rows (U,)). Scattering rows where
     ids >= 0 into the client store reproduces the encode-per-client path
     bit-for-bit (the codec is row-wise deterministic and union rows keep
-    ascending-gid order)."""
-    dec = comp.decode(codec, batch.payload, sh_k)
+    ascending-gid order).
+
+    A client decodes on one device of its own, so a payload that a mesh
+    spread over several devices is gathered to one first: a partitioned
+    decode may round a row differently in the last ulp, and the rows a
+    client decodes must not depend on the server's mesh."""
+    payload = batch.payload
+    devices = payload.pos_q.sharding.device_set
+    if len(devices) > 1:
+        payload = jax.device_put(payload, min(devices, key=lambda d: d.id))
+    dec = comp.decode(codec, payload, sh_k)
     ids = jnp.where(batch.ref_mask[client], batch.union_gids, -1)
     return ids, dec
 

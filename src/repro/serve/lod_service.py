@@ -693,15 +693,14 @@ def _compact_stale_pairs(stale: jax.Array, bucket: int, n_shards: int = 1,
     return sel_b, sel_s, valid
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("max_depth", "impl", "interpret", "mesh"))
+@functools.partial(jax.jit, static_argnames=("max_depth", "impl", "mesh"))
 def _pooled_pair_sweep(tables: ls.SlabTables, rpe, cams, taus, sel_b, sel_s,
-                       focal, *, max_depth: int, impl: str, interpret: bool,
-                       mesh=None):
+                       focal, *, max_depth: int, impl: str, mesh=None):
     """Gather the pooled pairs' slab attributes from the device-resident
     tables and sweep them — ONE fused program (the gathers never detour
     through the host). `impl` picks the vmapped XLA sweep or the Pallas
-    lod-cut kernel (`repro.kernels.lod_cut.lod_pair_sweep_pallas`).
+    lod-cut kernel (`repro.kernels.lod_cut.lod_pair_sweep_pallas`, compiled
+    on a TPU and interpreted on the CPU).
 
     Sharded fleets: the pair axis is constrained onto the `clients` axis
     (each shard's bucket lanes sweep on that shard); the slab-table gathers
@@ -710,15 +709,16 @@ def _pooled_pair_sweep(tables: ls.SlabTables, rpe, cams, taus, sel_b, sel_s,
     dispatch the partitioner cannot split, so under a mesh its pair inputs
     are explicitly REPLICATED first (correct but not scaled — prefer
     impl='xla' on a mesh)."""
+    tau_sel = taus[sel_b]
+    if impl == "pallas":
+        gathered = (tables.mu[sel_s], tables.size[sel_s], tables.end[sel_s],
+                    tables.is_leaf[sel_s], tables.valid[sel_s],
+                    rpe[sel_b, sel_s], cams[sel_b])
+        gathered, tau_sel = shd.replicate_fleet(mesh, (gathered, tau_sel))
+        return lc.lod_pair_sweep_pallas(*gathered, focal, tau_sel)
     gathered = (tables.mu[sel_s], tables.size[sel_s], tables.parent[sel_s],
                 tables.level[sel_s], tables.is_leaf[sel_s],
                 tables.valid[sel_s], rpe[sel_b, sel_s], cams[sel_b])
-    tau_sel = taus[sel_b]
-    if impl == "pallas":
-        gathered, tau_sel = shd.replicate_fleet(mesh, (gathered, tau_sel))
-        return lc.lod_pair_sweep_pallas(*gathered, focal, tau_sel,
-                                        max_depth=max_depth,
-                                        interpret=interpret)
     if mesh is not None:
         gathered = tuple(shd.constrain_fleet(
             g, ("clients",) + (None,) * (g.ndim - 1), mesh) for g in gathered)
@@ -736,7 +736,7 @@ def service_sync_pooled(tree: LodTree, cfg: SessionConfig,
                         page_size: Optional[int] = None,
                         participate=None,
                         tables: Optional[ls.SlabTables] = None,
-                        sweep_impl: str = "xla", interpret: bool = True,
+                        sweep_impl: str = "xla",
                         mesh=None) -> Tuple[ServiceState, ServiceStats,
                                             Optional[dp.DeltaBatch]]:
     """One LoD sync for every client with cross-client slab pooling.
@@ -825,8 +825,7 @@ def service_sync_pooled(tree: LodTree, cfg: SessionConfig,
                                                    n_shards=k, mesh=mesh)
         f_cut, f_rexp, f_rho = _pooled_pair_sweep(
             tables, rpe, cams, tau_b, sel_b, sel_s, jnp.float32(focal),
-            max_depth=m.slab_max_depth, impl=sweep_impl, interpret=interpret,
-            mesh=mesh)
+            max_depth=m.slab_max_depth, impl=sweep_impl, mesh=mesh)
         slab_cut, root_expand, rho, cam0 = _apply_pooled_updates(
             slab_cut, root_expand, rho, cam0, sel_b, sel_s,
             f_cut, f_rexp, f_rho, cams[sel_b], valid, guard=k > 1,
@@ -868,7 +867,7 @@ def _masked_queue(gaussians: Gaussians, gids: jax.Array) -> Gaussians:
 
 def service_render_step(tree: LodTree, state: ServiceState, rigs,
                         rcfg: "rnd.RenderConfig", *, path: str = "vmap",
-                        interpret: bool = True, mesh=None):
+                        mesh=None):
     """Render EVERY client's current cut queue cloud-side in one batched
     stereo dispatch (the fallback tier of Fig. 10: headsets too weak to run
     the client rasterizer receive pixels, not Gaussians).
@@ -893,7 +892,6 @@ def service_render_step(tree: LodTree, state: ServiceState, rigs,
                       )(state.cut_gids)
     queues = shd.shard_service_state(mesh, queues)
     return rnd.batched_render_stereo(queues, rigs, rcfg, path=path,
-                                     interpret=interpret,
                                      active=state.fleet.active, mesh=mesh)
 
 
@@ -907,8 +905,9 @@ class LodService:
     scheduler: "pooled" (cross-client bucketed hybrid, device-compacted —
     the production path) or "vmapped" (always-sweep exactness reference).
     `sweep_impl` selects the pooled bucket sweep: "xla" (vmapped) or
-    "pallas" (`repro.kernels.lod_cut.lod_pair_sweep_pallas`;
-    `interpret=True` is the CPU default — set False on real TPUs). `dedup`
+    "pallas" (`repro.kernels.lod_cut.lod_pair_sweep_pallas`, compiled on a
+    TPU backend and interpreted on the CPU — the backend decides, see
+    `repro.kernels.resolve_interpret`). `dedup`
     toggles the encode-once wire format (on by default; `dedup=False`
     restores per-client unicast accounting and skips the codec). `taus`
     optionally gives every client its own foveated LoD threshold
@@ -953,7 +952,6 @@ class LodService:
     def __init__(self, tree: LodTree, cfg: SessionConfig, n_clients: int,
                  focal: float, mode: str = "pooled", taus=None,
                  dedup: bool = True, sweep_impl: str = "xla",
-                 interpret: bool = True,
                  delta_budget: Optional[int] = None,
                  capacity: Optional[int] = None,
                  mesh=None, max_clients: Optional[int] = None,
@@ -985,7 +983,6 @@ class LodService:
         self.focal = float(focal)
         self.mode = mode
         self.sweep_impl = sweep_impl
-        self.interpret = bool(interpret)
         self.dedup = bool(dedup)
         # host-side control-plane mirror of state.fleet (slot lookup and
         # validation without device round-trips; the device FleetState is
@@ -1474,7 +1471,7 @@ class LodService:
             self.state, stats, batch = service_sync_pooled(
                 self.tree, self.cfg, self.state, self._slot_cams, self.focal,
                 self.bytes_per_g, tables=self.tables,
-                sweep_impl=self.sweep_impl, interpret=self.interpret, **kw)
+                sweep_impl=self.sweep_impl, **kw)
         else:
             self.state, stats, batch = service_sync_vmapped(
                 self.tree, self.cfg, self.state, self._slot_cams, self.focal,
@@ -1647,8 +1644,7 @@ class LodService:
         return rcfg, hit[1]
 
     def render_fallback(self, rigs, *, tile: int = 16, list_len: int = 256,
-                        max_pairs: int = 1 << 16, path: str = "vmap",
-                        interpret: bool = True):
+                        max_pairs: int = 1 << 16, path: str = "vmap"):
         """Fleet render of every live client's queue → (img_l, img_r, stats)
         with a leading SLOT axis (inactive slots render black).
 
@@ -1676,5 +1672,4 @@ class LodService:
                     n_cat=n_categories(max_disp, tile))
                 self._rcfg_cache[static_sig] = rcfg
         return service_render_step(self.tree, self.state, rigs, rcfg,
-                                   path=path, interpret=interpret,
-                                   mesh=self.mesh)
+                                   path=path, mesh=self.mesh)

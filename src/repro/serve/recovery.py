@@ -177,7 +177,6 @@ def snapshot_service(service: LodService, directory: str, step: int = 0, *,
             "focal": float(service.focal),
             "mode": service.mode,
             "sweep_impl": service.sweep_impl,
-            "interpret": bool(service.interpret),
             "dedup": bool(service.dedup),
             "page_size": int(service.page_size),
             "delta_budget_arg": (None if service._delta_budget_arg is None
@@ -261,7 +260,6 @@ def _restore_with_extras(tree: LodTree, directory: str,
         svc = LodService(
             tree, cfg, n_clients=0, focal=srv["focal"], mode=srv["mode"],
             dedup=srv["dedup"], sweep_impl=srv["sweep_impl"],
-            interpret=srv["interpret"],
             delta_budget=srv["delta_budget_arg"], capacity=capacity,
             mesh=mesh, max_clients=srv["max_clients"],
             max_state_bytes=srv["max_state_bytes"],

@@ -250,8 +250,6 @@ def fleet_totals(stats: Any, mesh: Optional[Mesh] = None):
     k = client_shards(mesh, int(cap))
     if k <= 1:
         return local(stats)
-    from jax.experimental.shard_map import shard_map
-
     def shardwise(s):
         return jax.tree_util.tree_map(
             lambda a: jax.lax.psum(a, "clients"), local(s))
@@ -259,8 +257,8 @@ def fleet_totals(stats: Any, mesh: Optional[Mesh] = None):
     in_specs = jax.tree_util.tree_map(
         lambda a: P(*(("clients",) + (None,) * (a.ndim - 1))), stats)
     out_specs = jax.tree_util.tree_map(lambda a: P(), stats)
-    return shard_map(shardwise, mesh=mesh, in_specs=(in_specs,),
-                     out_specs=out_specs, check_rep=False)(stats)
+    return jax.shard_map(shardwise, mesh=mesh, in_specs=(in_specs,),
+                         out_specs=out_specs, check_vma=False)(stats)
 
 
 def shard_resident_bytes(mesh: Optional[Mesh], *trees: Any) -> int:

@@ -148,6 +148,24 @@ def test_paged_stream_ships_coarse_rows_first(small_tree):
     assert set(shipped.tolist()) == want
 
 
+def test_union_ranking_compiles_once_across_stream_widths(small_tree):
+    """The N-row priority sort is keyed on the table size only: unions that
+    land in different pow2 stream widths reuse one compiled ranking (on a
+    TPU each compile of that sort takes tens of seconds at city scale)."""
+    rng = np.random.default_rng(3)
+    codec, _ = session_wire_format(small_tree, SessionConfig(tau=TAU))
+    widths, traced = set(), None
+    for budget in (64, 256, 1024):
+        masks = _masks_for_overlap(small_tree.n_pad, 3, 0.5, rng)
+        batch = dp.build_delta_batch(small_tree.gaussians, codec,
+                                     jnp.asarray(masks), budget,
+                                     priority=small_tree.node_levels())
+        widths.add(batch.union_gids.shape[0])
+        traced = traced or dp._rank_union._cache_size()
+    assert widths == {64, 256, 1024}
+    assert dp._rank_union._cache_size() == traced
+
+
 def test_first_owner_counts_partition_union(small_tree):
     rng = np.random.default_rng(5)
     masks = _masks_for_overlap(small_tree.n_pad, 4, 0.5, rng)

@@ -17,7 +17,9 @@ from repro.core.gaussians import random_gaussians
 from repro.core.projection import depth_ranks, project
 from repro.core.raster import render_tiles
 from repro.core.stereo import n_categories, stereo_lists
-from repro.kernels import ops, ref as kref
+from repro.kernels import ops, ref as kref, resolve_interpret
+from repro.kernels.preprocess import preprocess_pallas
+from repro.kernels.stereo_shift import stereo_merge_pallas
 
 
 def _scene(n=300, seed=0, width=96, height=64, focal=200.0):
@@ -175,8 +177,31 @@ def test_flash_attention_kernel(b, h, hkv, lq, lk, d, causal, window, dtype):
     k = jnp.asarray(rng.normal(size=(b, hkv, lk, d)), dtype)
     v = jnp.asarray(rng.normal(size=(b, hkv, lk, d)), dtype)
     out_p = ops.flash_attention(q, k, v, causal=causal, window=window,
-                                use_pallas=True, interpret=True)
+                                use_pallas=True)
     out_r = kref.ref_attention(q, k, v, causal=causal, window=window)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(out_p, np.float32),
                                np.asarray(out_r, np.float32), rtol=tol, atol=tol)
+
+
+# -- interpret decision ---------------------------------------------------------
+
+
+def test_interpret_follows_the_backend():
+    """One decision: interpret on the CPU backend unless told otherwise."""
+    assert resolve_interpret() is True
+    assert resolve_interpret(False) is False and resolve_interpret(True) is True
+
+
+@pytest.mark.parametrize("kernel,args", [
+    (stereo_merge_pallas, (jnp.zeros((8, 2, 128), jnp.int32),
+                           jnp.zeros((8, 2, 128), jnp.int32))),
+    (preprocess_pallas, (jnp.zeros((256, 3)), jnp.zeros((256, 3)),
+                         jnp.zeros((256, 4)), jnp.zeros((256,)),
+                         jnp.zeros((256, 4, 3)), jnp.zeros((26,)))),
+])
+def test_unlowered_kernels_refuse_compiled_calls(kernel, args):
+    """Kernels Mosaic cannot lower raise when asked to compile, instead of
+    silently running the interpreter on a TPU."""
+    with pytest.raises(NotImplementedError, match="Mosaic"):
+        kernel(*args, interpret=False)

@@ -1,10 +1,13 @@
 """LoD tree construction invariants."""
 
+import dataclasses
+
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.gaussians import random_gaussians
-from repro.core.lod_tree import build_lod_tree
+from repro.core.lod_tree import build_lod_tree, slab_subtree_end
 from repro.core.lod_search import global_level_np, global_parent_np
 
 
@@ -33,7 +36,7 @@ def _check_invariants(tree):
     # slab roots have their parent in the top-tree
     rpt = np.asarray(tree.slab_root_parent_top)
     assert ((rpt >= 0) & (rpt < m.T)).all()
-    # slab-local parents precede their children (BFS order)
+    # slab-local parents precede their children (DFS preorder)
     sp = np.asarray(tree.slab_parent)
     sv = np.asarray(tree.slab_valid)
     jj = np.broadcast_to(np.arange(m.S), (m.Ns, m.S))
@@ -63,3 +66,47 @@ def test_padding_is_inert(small_tree):
     sv = np.asarray(small_tree.slab_valid)
     size = np.asarray(small_tree.slab_size())
     assert (size[~sv] == 0).all()
+
+
+def _subtree_end_brute(tree):
+    """One past each node's last descendant, by walking parent pointers."""
+    parent = np.asarray(tree.slab_parent)
+    valid = np.asarray(tree.slab_valid)
+    end = np.where(valid, np.arange(tree.meta.S)[None, :] + 1, 0)
+    for s in range(tree.meta.Ns):
+        for j in np.flatnonzero(valid[s]):
+            a = parent[s, j]
+            while a >= 0:
+                end[s, a] = max(end[s, a], j + 1)
+                a = parent[s, a]
+    return end
+
+
+@pytest.mark.parametrize("fixture", ["small_tree", "tiny_tree"])
+def test_slabs_are_dfs_preorder(fixture, request):
+    """Each node's subtree is the slab range [j, end[j]) — the layout the
+    Pallas sweep reads ancestry from."""
+    tree = request.getfixturevalue(fixture)
+    np.testing.assert_array_equal(slab_subtree_end(tree),
+                                  _subtree_end_brute(tree))
+
+
+def test_subtree_end_rejects_level_order_slabs(small_tree):
+    """A slab re-laid in level (BFS) order breaks the contiguous-subtree
+    contract; the check refuses it instead of feeding the kernel."""
+    parent = np.asarray(small_tree.slab_parent)
+    level = np.asarray(small_tree.slab_level)
+    valid = np.asarray(small_tree.slab_valid)
+    new_p, new_l, new_v = parent.copy(), level.copy(), valid.copy()
+    for s in range(small_tree.meta.Ns):
+        order = np.lexsort((np.arange(small_tree.meta.S), level[s]))
+        inv = np.empty_like(order)
+        inv[order] = np.arange(order.size)
+        p = parent[s][order]
+        new_p[s] = np.where(p >= 0, inv[np.clip(p, 0, None)], -1)
+        new_l[s], new_v[s] = level[s][order], valid[s][order]
+    bfs = dataclasses.replace(small_tree, slab_parent=jnp.asarray(new_p),
+                              slab_level=jnp.asarray(new_l),
+                              slab_valid=jnp.asarray(new_v))
+    with pytest.raises(ValueError, match="DFS preorder"):
+        slab_subtree_end(bfs)

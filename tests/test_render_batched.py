@@ -96,12 +96,10 @@ def test_pooled_bucket_path_matches_per_client_kernels():
                                      max_pairs=1 << 14)
     qs, rs = rnd.stack_pytrees(queues), rnd.stack_rigs(rigs)
     xl, xr, xstats = rnd.batched_render_stereo(qs, rs, cfg, path="vmap")
-    pl_l, pl_r, pstats = rnd.batched_render_stereo(qs, rs, cfg, path="pooled",
-                                                   interpret=True)
+    pl_l, pl_r, pstats = rnd.batched_render_stereo(qs, rs, cfg, path="pooled")
     for i in range(b):
         plan = rnd.build_plan(queues[i], rigs[i], cfg)
-        il, ir, _hits = rnd.rasterize(plan, cfg, use_pallas=True,
-                                      interpret=True)
+        il, ir, _hits = rnd.rasterize(plan, cfg, use_pallas=True)
         np.testing.assert_array_equal(np.asarray(pl_l[i]), np.asarray(il))
         np.testing.assert_array_equal(np.asarray(pl_r[i]), np.asarray(ir))
     np.testing.assert_allclose(np.asarray(pl_l), np.asarray(xl),

@@ -400,6 +400,8 @@ def cmp_sync(tag, sb, ss, base, shrd):
     for cid in base.active_ids:
         ib, dbv = base.client_delta(cid)
         is_, dsv = shrd.client_delta(cid)
+        # a client decodes on one device, whatever the server's mesh
+        assert len(dsv.mu.sharding.device_set) == 1, tag
         np.testing.assert_array_equal(np.asarray(ib), np.asarray(is_),
                                       err_msg=f"{tag}:ids:{cid}")
         sel = np.asarray(ib) >= 0

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.models.mamba2 import ssd_chunked, ssd_step
 from repro.models.xlstm import (mlstm_chunked, mlstm_recurrent_step,
