@@ -116,7 +116,7 @@ def _run_deadline(tree, cfg, n_normal, n_straggler, tier, paths, deliver,
         sched.set_deadline(c, loose_ms if i >= n_normal else tight_ms)
         sched.observe_motion(c, paths[i][0])
     sched.tick()  # warm/compile tick outside the measured window
-    sched._mtp_samples.clear()
+    sched.recorder.drain()
     for f in range(deliver.shape[0]):
         for i, c in enumerate(ids):
             if deliver[f, i]:
@@ -126,8 +126,9 @@ def _run_deadline(tree, cfg, n_normal, n_straggler, tier, paths, deliver,
     for _ in range(16):
         if sched.tick() is None:
             break
-    mtp = np.asarray([s[0] for s in sched._mtp_samples])
-    miss = np.asarray([s[1] for s in sched._mtp_samples], bool)
+    records = sched.recorder.drain()
+    mtp = np.concatenate([r["wait_ms"] + r["service_ms"] for r in records])
+    miss = np.concatenate([r["missed"] for r in records])
     return mtp, miss, sched
 
 

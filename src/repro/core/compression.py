@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.gaussians import Gaussians
+from repro.serve import tracing
 
 
 @jax.tree_util.register_dataclass
@@ -138,6 +139,7 @@ def _dequant16(q, lo, hi):
 
 
 @jax.jit
+@tracing.scoped("delta.union")
 def encode(codec: Codec, g: Gaussians) -> EncodedGaussians:
     n, k = g.sh.shape[0], g.sh.shape[1]
     if k > 1:

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.lod_tree import LodTree, slab_subtree_end
+from repro.serve import tracing
 
 _EPS_DIST = 1e-6
 
@@ -350,16 +351,35 @@ def _sweep_selected(slab_mu, slab_size, slab_parent, slab_level, slab_is_leaf,
                         slab_is_leaf, slab_valid, rpe_sel)
 
 
-@functools.partial(jax.jit, static_argnames=())
-def _top_and_staleness(tree: LodTree, state: TemporalState, cam_pos, focal, tau):
+def _top_and_terms(tree: LodTree, state: TemporalState, cam_pos, focal, tau):
+    """Top-tree sweep and the three terms of the staleness predicate, each
+    (Ns,) bool: the slab was never swept, its root's parent-expand bit
+    changed, the camera moved at least the reuse radius ρ."""
     top_expand, top_cut = top_sweep(tree, cam_pos, focal, tau)
     rpe = _root_parent_expand(tree, top_expand)
     moved = jnp.linalg.norm(cam_pos - state.cam0, axis=-1)
-    stale = (~state.swept) | (moved >= state.rho) | (rpe != state.parent_expand0)
-    return top_cut, rpe, stale
+    return (top_cut, rpe, ~state.swept, rpe != state.parent_expand0,
+            moved >= state.rho)
+
+
+@functools.partial(jax.jit, static_argnames=())
+def _top_and_staleness(tree: LodTree, state: TemporalState, cam_pos, focal, tau):
+    top_cut, rpe, cold, parent, moved = _top_and_terms(tree, state, cam_pos,
+                                                       focal, tau)
+    return top_cut, rpe, cold | moved | parent
+
+
+def stale_causes(cold, parent, moved) -> jax.Array:
+    """(3,) int32 counts of stale pairs by first cause: never swept
+    (cold), else parent expansion changed, else moved at least ρ. They sum
+    to the stale pairs."""
+    parent = parent & ~cold
+    moved = moved & ~(cold | parent)
+    return jnp.stack([cold.sum(), parent.sum(), moved.sum()]).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("mesh",))
+@tracing.scoped("lod.staleness")
 def batched_top_and_staleness(tree: LodTree, states: TemporalState,
                               cam_positions: jax.Array, focal, tau,
                               active=None, *, mesh=None):
@@ -367,7 +387,8 @@ def batched_top_and_staleness(tree: LodTree, states: TemporalState,
     per-subtree staleness predicate, vmapped over B clients. `tau` is a
     scalar or a (B,) per-client vector (foveated LoD).
 
-    Returns (top_cut (B,T), rpe (B,Ns), stale (B,Ns)). The expensive phase —
+    Returns (top_cut (B,T), rpe (B,Ns), stale (B,Ns), causes (3,) int32:
+    the stale pairs counted by `stale_causes`). The expensive phase —
     sweeping only the stale (client, slab) pairs — is host-scheduled across
     clients by repro.serve.lod_service.
 
@@ -384,20 +405,24 @@ def batched_top_and_staleness(tree: LodTree, states: TemporalState,
     cam_positions = jnp.asarray(cam_positions, jnp.float32)
     taus = jnp.broadcast_to(jnp.asarray(tau, jnp.float32),
                             (cam_positions.shape[0],))
-    top_cut, rpe, stale = jax.vmap(
-        _top_and_staleness, in_axes=(None, 0, 0, None, 0))(
+    top_cut, rpe, cold, parent, moved = jax.vmap(
+        _top_and_terms, in_axes=(None, 0, 0, None, 0))(
         tree, states, cam_positions, focal, taus)
     if active is not None:
-        stale = stale & active[:, None]
+        on = active[:, None]
+        cold, parent, moved = cold & on, parent & on, moved & on
+    stale = cold | moved | parent
+    causes = stale_causes(cold, parent, moved)
     if mesh is not None:
         from repro.sharding.fleet import constrain_fleet
         top_cut = constrain_fleet(top_cut, ("clients", None), mesh)
         rpe = constrain_fleet(rpe, ("clients", None), mesh)
         stale = constrain_fleet(stale, ("clients", None), mesh)
-    return top_cut, rpe, stale
+    return top_cut, rpe, stale, causes
 
 
 @functools.partial(jax.jit, static_argnames=())
+@tracing.scoped("lod.staleness")
 def predicted_stale_counts(tree: LodTree, states: TemporalState,
                            cam_positions: jax.Array, focal, tau,
                            active=None) -> jax.Array:

@@ -28,6 +28,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.serve import tracing
+
 ID_BYTES = 4          # plain 32-bit ids on the wire
 ID_BYTES_DELTA = 2    # delta-coded ids (sorted ascending, varint-ish) — model
 SYNC_HEADER_BYTES = 64
@@ -145,6 +147,7 @@ def gather_payload(tree_gaussians, delta_mask: jax.Array, budget: int):
 
 
 @functools.partial(jax.jit, static_argnames=())
+@tracing.scoped("table.update")
 def batched_cloud_sync(states: ManagerState, cut_masks: jax.Array,
                        ts: jax.Array, w_star: jax.Array
                        ) -> Tuple[ManagerState, SyncPlan]:

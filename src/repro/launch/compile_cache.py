@@ -19,7 +19,13 @@ def enable_compilation_cache() -> str:
     """Turn the persistent compilation cache on and return its directory.
 
     `JAX_COMPILATION_CACHE_DIR`, when set, is honoured as is (JAX reads it
-    itself); otherwise the cache lives at the checkout's `.jax_cache/`."""
+    itself); otherwise the cache lives at the checkout's `.jax_cache/`.
+
+    The cache key includes each program's op metadata: by default JAX
+    strips it, so a program whose source differs only in its trace scopes
+    (`repro.serve.tracing`) would load an executable built without them,
+    and its device time would fall outside every stage of a trace."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

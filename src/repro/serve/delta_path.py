@@ -62,6 +62,7 @@ import numpy as np
 from repro.core import compression as comp
 from repro.core import lod_search as ls
 from repro.core.gaussians import Gaussians
+from repro.serve import tracing
 
 
 @jax.tree_util.register_dataclass
@@ -123,6 +124,7 @@ _PRIO_PAD = 2**31 - 1  # non-members sort after every real row
 
 
 @jax.jit
+@tracing.scoped("delta.union")
 def _union_mask(wanted: jax.Array, priority: jax.Array):
     """The size of the sync's union and every row's rank key: one int32
     ordering rows by (priority asc, requester count desc), non-members
@@ -137,6 +139,7 @@ def _union_mask(wanted: jax.Array, priority: jax.Array):
 
 
 @jax.jit
+@tracing.scoped("delta.union")
 def _rank_union(key: jax.Array, n_union: jax.Array, width: jax.Array):
     """Rank every row by its key, ties by gid (a stable sort of the gids),
     and lay the top min(n_union, width) ranks out in wire order (ascending
@@ -156,6 +159,7 @@ def _rank_union(key: jax.Array, n_union: jax.Array, width: jax.Array):
 
 
 @functools.partial(jax.jit, static_argnames=("width", "page_size", "mesh"))
+@tracing.scoped("delta.union")
 def _union_refs(wanted: jax.Array, by_rank: jax.Array, rank_of: jax.Array,
                 wire: jax.Array, n_union: jax.Array, allowance: jax.Array,
                 width: int, page_size: int, mesh=None):
@@ -264,7 +268,8 @@ def build_delta_batch(gaussians: Gaussians, codec: comp.Codec,
     if priority is None:
         priority = jnp.zeros((n_rows,), jnp.int32)
     n_union, key = _union_mask(wanted, priority)
-    width = ls.pow2_bucket(int(jax.device_get(n_union)), budget)
+    with tracing.span("delta.union_size_read"):
+        width = ls.pow2_bucket(int(jax.device_get(n_union)), budget)
     allow = (jnp.full((b,), width, jnp.int32) if allowance is None
              else jnp.asarray(allowance, jnp.int32))
     psize = width if page_size is None else max(1, min(int(page_size), width))
@@ -383,6 +388,7 @@ def lost_row_mask(batch: DeltaBatch, client: int, lost_pages) -> np.ndarray:
 
 
 @jax.jit
+@tracing.scoped("table.update")
 def first_owner_counts(delta_masks: jax.Array) -> jax.Array:
     """(B,) int32 — per client, the number of its Δ rows for which it is the
     fleet's *first* requester (lowest client index). Partitions the union:

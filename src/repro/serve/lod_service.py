@@ -92,6 +92,7 @@ from repro.core.pipeline import SessionConfig, session_wire_format
 from repro.kernels import lod_cut as lc
 from repro.serve import delta_path as dp
 from repro.serve import fleet as flt
+from repro.serve import tracing
 from repro.sharding import fleet as shd
 from repro import render as rnd
 
@@ -310,6 +311,7 @@ def service_shrink(state: ServiceState, perm) -> ServiceState:
 
 
 @functools.partial(jax.jit, static_argnames=("budget", "mesh"))
+@tracing.scoped("table.update")
 def _batched_cut_gids(masks: jax.Array, budget: int, mesh=None):
     def one(m):
         (g,) = jnp.nonzero(m, size=budget, fill_value=-1)
@@ -611,6 +613,7 @@ def service_sync_vmapped(tree: LodTree, cfg: SessionConfig,
 
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3),
                    static_argnames=("guard", "mesh"))
+@tracing.scoped("table.update")
 def _apply_pooled_updates(slab_cut, root_expand, rho, cam0, sel_b, sel_s,
                           f_cut, f_rexp, f_rho, cam_sel, valid=None, *,
                           guard: bool = False, mesh=None):
@@ -640,6 +643,7 @@ def _apply_pooled_updates(slab_cut, root_expand, rho, cam0, sel_b, sel_s,
 
 
 @functools.partial(jax.jit, static_argnames=("n_shards", "mesh"))
+@tracing.scoped("lod.staleness")
 def _shard_stale_counts(stale: jax.Array, n_shards: int, mesh=None):
     """(n_shards,) stale-pair counts, one per client shard — the ONE host
     transfer of a sharded pooled sync (each shard's count picks the shared
@@ -649,6 +653,7 @@ def _shard_stale_counts(stale: jax.Array, n_shards: int, mesh=None):
 
 
 @functools.partial(jax.jit, static_argnames=("bucket", "n_shards", "mesh"))
+@tracing.scoped("lod.staleness")
 def _compact_stale_pairs(stale: jax.Array, bucket: int, n_shards: int = 1,
                          mesh=None):
     """On-device compaction of the (B, Ns) staleness mask into per-client-
@@ -694,6 +699,7 @@ def _compact_stale_pairs(stale: jax.Array, bucket: int, n_shards: int = 1,
 
 
 @functools.partial(jax.jit, static_argnames=("max_depth", "impl", "mesh"))
+@tracing.scoped("lod.pair_sweep")
 def _pooled_pair_sweep(tables: ls.SlabTables, rpe, cams, taus, sel_b, sel_s,
                        focal, *, max_depth: int, impl: str, mesh=None):
     """Gather the pooled pairs' slab attributes from the device-resident
@@ -709,21 +715,28 @@ def _pooled_pair_sweep(tables: ls.SlabTables, rpe, cams, taus, sel_b, sel_s,
     dispatch the partitioner cannot split, so under a mesh its pair inputs
     are explicitly REPLICATED first (correct but not scaled — prefer
     impl='xla' on a mesh)."""
-    tau_sel = taus[sel_b]
     if impl == "pallas":
-        gathered = (tables.mu[sel_s], tables.size[sel_s], tables.end[sel_s],
-                    tables.is_leaf[sel_s], tables.valid[sel_s],
-                    rpe[sel_b, sel_s], cams[sel_b])
+        with tracing.scope("lod.pair_sweep/gather"):
+            tau_sel = taus[sel_b]
+            gathered = (tables.mu[sel_s], tables.size[sel_s],
+                        tables.end[sel_s], tables.is_leaf[sel_s],
+                        tables.valid[sel_s], rpe[sel_b, sel_s],
+                        cams[sel_b])
         gathered, tau_sel = shd.replicate_fleet(mesh, (gathered, tau_sel))
         return lc.lod_pair_sweep_pallas(*gathered, focal, tau_sel)
-    gathered = (tables.mu[sel_s], tables.size[sel_s], tables.parent[sel_s],
-                tables.level[sel_s], tables.is_leaf[sel_s],
-                tables.valid[sel_s], rpe[sel_b, sel_s], cams[sel_b])
+    with tracing.scope("lod.pair_sweep/gather"):
+        tau_sel = taus[sel_b]
+        gathered = (tables.mu[sel_s], tables.size[sel_s],
+                    tables.parent[sel_s], tables.level[sel_s],
+                    tables.is_leaf[sel_s], tables.valid[sel_s],
+                    rpe[sel_b, sel_s], cams[sel_b])
     if mesh is not None:
         gathered = tuple(shd.constrain_fleet(
-            g, ("clients",) + (None,) * (g.ndim - 1), mesh) for g in gathered)
+            g, ("clients",) + (None,) * (g.ndim - 1), mesh)
+            for g in gathered)
         tau_sel = shd.constrain_fleet(tau_sel, ("clients",), mesh)
-    return ls.sweep_slab_camera_pairs(*gathered, focal, tau_sel, max_depth)
+    return ls.sweep_slab_camera_pairs(*gathered, focal, tau_sel,
+                                      max_depth)
 
 
 def service_sync_pooled(tree: LodTree, cfg: SessionConfig,
@@ -737,8 +750,9 @@ def service_sync_pooled(tree: LodTree, cfg: SessionConfig,
                         participate=None,
                         tables: Optional[ls.SlabTables] = None,
                         sweep_impl: str = "xla",
-                        mesh=None) -> Tuple[ServiceState, ServiceStats,
-                                            Optional[dp.DeltaBatch]]:
+                        mesh=None, account: Optional[dict] = None
+                        ) -> Tuple[ServiceState, ServiceStats,
+                                   Optional[dp.DeltaBatch]]:
     """One LoD sync for every client with cross-client slab pooling.
 
     The batched analog of `temporal_search_hybrid`, now device-scheduled:
@@ -781,6 +795,12 @@ def service_sync_pooled(tree: LodTree, cfg: SessionConfig,
     repeat-padding differs per shard but padded lanes rewrite identical
     values, and an empty shard's lanes are guarded no-ops.
 
+    `account`, a dict when given, receives what the sync decided: `n_stale`
+    (the stale-pair pool, the host count read above), `lanes` (the pair
+    lanes the bucket swept: bucket × client shards, 0 when nothing was
+    stale) and `stale_causes` (the (3,) int32 device counts of
+    `lod_search.stale_causes`, never read here).
+
     NOTE: like `temporal_search_hybrid`, the scatter donates the incoming
     `state.temporal` buffers (no (B, Ns, S) re-copy per sync). On backends
     that honor donation the input state is CONSUMED — keep using the
@@ -799,23 +819,27 @@ def service_sync_pooled(tree: LodTree, cfg: SessionConfig,
     # inactive slots report zero staleness, so they never enter the pool:
     # sweep work (and the pool-size scalars below) tracks the ACTIVE fleet
     # — and, on a partial tick, only its SELECTED subset
-    top_cut, rpe, stale = ls.batched_top_and_staleness(
+    top_cut, rpe, stale, causes = ls.batched_top_and_staleness(
         tree, state.temporal, cams, jnp.float32(focal), tau_b, eff,
         mesh=mesh)
     k = shd.client_shards(mesh, stale.shape[0])
     # the ONE host synchronization of the sync: pool-size scalars — global
     # for the meshless service, one per client shard under a mesh
     if k > 1:
-        shard_counts = np.asarray(
-            jax.device_get(_shard_stale_counts(stale, k, mesh=mesh)))
+        counts = _shard_stale_counts(stale, k, mesh=mesh)
+        with tracing.span("svc.stale_count_read"):
+            shard_counts = np.asarray(jax.device_get(counts))
         n_stale = int(shard_counts.sum())
     else:
-        n_stale = int(jax.device_get(stale.sum()))
+        count = stale.sum()
+        with tracing.span("svc.stale_count_read"):
+            n_stale = int(jax.device_get(count))
     n_pairs = stale.shape[0] * stale.shape[1]
 
     tp = state.temporal
     slab_cut, root_expand, rho, cam0 = (tp.slab_cut0, tp.root_expand0,
                                         tp.rho, tp.cam0)
+    bucket = 0
     if n_stale > 0:
         if k > 1:
             bucket = ls.pow2_bucket(int(shard_counts.max()), n_pairs // k)
@@ -830,6 +854,9 @@ def service_sync_pooled(tree: LodTree, cfg: SessionConfig,
             slab_cut, root_expand, rho, cam0, sel_b, sel_s,
             f_cut, f_rexp, f_rho, cams[sel_b], valid, guard=k > 1,
             mesh=mesh)
+    if account is not None:
+        account.update(n_stale=n_stale, lanes=bucket * k,
+                       stale_causes=causes)
 
     # the eff-masked scatter never touches a non-participating slot's
     # donated buffers; freeze the two non-donated leaves the same way so
@@ -1064,6 +1091,11 @@ class LodService:
                                     capacity=self.capacity))
         self.last_delta: Optional[dp.DeltaBatch] = None
         self._delta_ids = np.full(self.capacity, -1, np.int64)
+        # syncs run so far: the `tick` of every trace span of the next sync
+        self.syncs = 0
+        # what the last pooled sync decided (`service_sync_pooled`'s
+        # `account`); empty after a vmapped sync, which pools nothing
+        self.last_account: dict = {}
         self._rcfg_cache = {}
         self._stack_cache = {}
 
@@ -1430,7 +1462,16 @@ class LodService:
         Under partial ticks the controller only commits a slot's update
         when that slot's measurement is fresh (it participated in the
         previous sync) — a stale measurement is never fed through the
-        multiplicative loop twice."""
+        multiplicative loop twice.
+
+        The sync runs in the trace span `nebula.svc.sync` (`tracing`), with
+        a span on each blocking read inside it."""
+        with tracing.span("svc.sync", tick=self.syncs):
+            stats = self._sync(cam_positions, participate)
+        self.syncs += 1
+        return stats
+
+    def _sync(self, cam_positions, participate) -> ServiceStats:
         part_mask = self._participation_mask(participate)
         if isinstance(cam_positions, dict):
             updates = {self._slot_of(cid): np.asarray(pos, np.float32)
@@ -1446,8 +1487,9 @@ class LodService:
         allowance, taus_eff = None, self.taus
         if self.dedup and np.isfinite(self._bw_target).any():
             if self._last_stats is not None:
-                measured = np.asarray(self._last_stats.sync_bytes,
-                                      np.float64)
+                with tracing.span("svc.rate_read"):
+                    measured = np.asarray(self._last_stats.sync_bytes,
+                                          np.float64)
                 new_allow, new_tau = rate_control_step(
                     self._bw_target, measured, self._allowance,
                     self._tau_scale, page_size=self.page_size,
@@ -1467,11 +1509,12 @@ class LodService:
                   delta_budget=self.delta_budget, priority=self._priority,
                   allowance=allowance, page_size=self.page_size,
                   participate=part_mask, mesh=self.mesh)
+        self.last_account = {}
         if self.mode == "pooled":
             self.state, stats, batch = service_sync_pooled(
                 self.tree, self.cfg, self.state, self._slot_cams, self.focal,
                 self.bytes_per_g, tables=self.tables,
-                sweep_impl=self.sweep_impl, **kw)
+                sweep_impl=self.sweep_impl, account=self.last_account, **kw)
         else:
             self.state, stats, batch = service_sync_vmapped(
                 self.tree, self.cfg, self.state, self._slot_cams, self.focal,

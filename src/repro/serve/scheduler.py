@@ -38,9 +38,12 @@ How a tick works (`DeadlineScheduler.tick`):
 MTP accounting: a client's motion-to-photon sample is the wall-clock time
 from its OLDEST unserved `observe_motion` to the completion of the sync
 that served it — the serving-side half of the paper's MTP latency (client
-decode/render ride on top). `stats_summary()` reduces the rolling window
-to p50/p99 MTP and the deadline-miss rate. The clock is injectable, so
-tests drive deterministic schedules.
+decode/render ride on top). It splits into the client's queue wait (to the
+start of the tick's sync) and the tick's service time; each tick adds both
+per served client, with the deadline misses and what the sync's stale-pair
+pool held, to `recorder` (a `tracing.Recorder`, drained by the operator).
+Each tick runs in the trace span `nebula.sched.tick` (`tracing`). The clock
+is injectable, so tests drive deterministic schedules.
 
 Predicted-cost admission (`DeadlineScheduler.admit`): an admit is DENIED
 (`AdmissionDenied`, or None with `required=False`) when the cost model says
@@ -67,6 +70,7 @@ import jax
 import numpy as np
 
 from repro.core import lod_search as ls
+from repro.serve import tracing
 from repro.serve.lod_service import (AdmissionDenied, LodService,
                                      ServiceStats)
 
@@ -143,7 +147,8 @@ class DeadlineScheduler:
     """Deadline/priority scheduler over a live `LodService` (see module
     docstring). `clock` is any zero-arg monotonic-seconds callable
     (default `time.monotonic`); tests inject a scripted one.
-    `tick_budget_ms=None` removes the per-tick cost budget (pure EDF)."""
+    `tick_budget_ms=None` removes the per-tick cost budget (pure EDF).
+    `recorder` (a `tracing.Recorder`) keeps the per-tick account."""
 
     VELOCITY_SMOOTHING = 0.3
     PAIRS_SMOOTHING = 0.3
@@ -152,7 +157,7 @@ class DeadlineScheduler:
                  default_deadline_ms: float = DEFAULT_DEADLINE_MS,
                  tick_budget_ms: Optional[float] = None,
                  cost_model: Optional[CostModel] = None,
-                 clock=None, window: int = 1024):
+                 clock=None):
         self.service = service
         self.default_deadline_ms = float(default_deadline_ms)
         self.tick_budget_ms = (None if tick_budget_ms is None
@@ -160,8 +165,7 @@ class DeadlineScheduler:
         self.cost = CostModel() if cost_model is None else cost_model
         self._clock = time.monotonic if clock is None else clock
         self._clients: Dict[int, _ClientSched] = {}
-        # rolling (mtp_ms, missed) samples across the fleet
-        self._mtp_samples: deque = deque(maxlen=int(window))
+        self.recorder = tracing.Recorder()
         self._ns = int(service.tree.meta.Ns)
         for cid in service.active_ids:
             self._register(cid, None)
@@ -278,9 +282,11 @@ class DeadlineScheduler:
                 cams[svc._slot_of(cid)] = c.pending_cam
         taus = (svc.taus if svc.taus is not None
                 else np.full(svc.capacity, svc.cfg.tau, np.float32))
-        counts = np.asarray(jax.device_get(ls.predicted_stale_counts(
+        counts = ls.predicted_stale_counts(
             svc.tree, svc.state.temporal, cams, svc.focal, taus,
-            svc.state.fleet.active)))
+            svc.state.fleet.active)
+        with tracing.span("sched.preview_read"):
+            counts = np.asarray(jax.device_get(counts))
         return {cid: int(counts[svc._slot_of(cid)])
                 for cid in self._clients}
 
@@ -317,55 +323,48 @@ class DeadlineScheduler:
 
     def tick(self, now: Optional[float] = None) -> Optional[ServiceStats]:
         """Run one scheduler tick: select, partial-sync, time, refit the
-        cost model, stamp MTP columns. Returns the stamped per-slot stats,
-        or None when no client had unserved motion (nothing to do — an
-        idle fleet costs nothing)."""
+        cost model, stamp MTP columns, add the tick to `recorder`. Returns
+        the stamped per-slot stats, or None when no client had unserved
+        motion (nothing to do — an idle fleet costs nothing)."""
         svc = self.service
-        selected = self.select(now)
-        if not selected:
-            return None
-        cams = {cid: self._clients[cid].pending_cam for cid in selected}
-        t0 = self._clock()
-        stats = svc.sync(cams, participate=selected)
-        jax.block_until_ready(stats.sync_bytes)
-        t_done = self._clock()
-        resweeps = np.asarray(jax.device_get(stats.resweeps))
+        index = svc.syncs
+        with tracing.span("sched.tick", tick=index):
+            with tracing.span("sched.select"):
+                selected = self.select(now)
+            if not selected:
+                return None
+            cams = {cid: self._clients[cid].pending_cam for cid in selected}
+            t0 = self._clock()
+            stats = svc.sync(cams, participate=selected)
+            with tracing.span("sched.wait"):
+                jax.block_until_ready(stats.sync_bytes)
+                t_done = self._clock()
+                resweeps = np.asarray(jax.device_get(stats.resweeps))
         self.cost.observe(float(resweeps.sum()), (t_done - t0) * 1e3)
+        slots = [svc._slot_of(cid) for cid in selected]
+        wait_ms = np.array([(t0 - self._clients[cid].oldest_motion_at) * 1e3
+                            for cid in selected])
         mtp_col = np.zeros(svc.capacity, np.float32)
         miss_col = np.zeros(svc.capacity, bool)
-        for cid in selected:
+        for cid, slot in zip(selected, slots):
             c = self._clients[cid]
-            slot = svc._slot_of(cid)
             s = self.PAIRS_SMOOTHING
             c.ewma_pairs = ((1 - s) * c.ewma_pairs
                             + s * float(resweeps[slot]))
             mtp = (t_done - c.oldest_motion_at) * 1e3
-            missed = mtp > c.deadline_ms
             mtp_col[slot] = mtp
-            miss_col[slot] = missed
-            self._mtp_samples.append((mtp, missed))
+            miss_col[slot] = mtp > c.deadline_ms
             c.last_sync_at = t_done
             c.oldest_motion_at = None
             c.pending_cam = None
+        self.recorder.add(
+            tick=index, **svc.last_account,
+            client=np.asarray(selected, np.int64), wait_ms=wait_ms,
+            service_ms=np.full(len(selected), (t_done - t0) * 1e3),
+            missed=miss_col[slots])
         return dataclasses.replace(
             stats, mtp_ms=jax.numpy.asarray(mtp_col),
             deadline_miss=jax.numpy.asarray(miss_col))
-
-    # -- accounting -----------------------------------------------------------
-
-    def stats_summary(self) -> Dict[str, float]:
-        """Reduce the rolling MTP window: p50/p99 motion-to-photon ms and
-        the deadline-miss rate (fraction of served motion samples that
-        overran their client's deadline)."""
-        if not self._mtp_samples:
-            return {"n": 0, "mtp_p50_ms": 0.0, "mtp_p99_ms": 0.0,
-                    "deadline_miss_rate": 0.0}
-        mtp = np.array([s[0] for s in self._mtp_samples], np.float64)
-        miss = np.array([s[1] for s in self._mtp_samples], bool)
-        return {"n": int(mtp.size),
-                "mtp_p50_ms": float(np.percentile(mtp, 50)),
-                "mtp_p99_ms": float(np.percentile(mtp, 99)),
-                "deadline_miss_rate": float(miss.mean())}
 
     # -- persistence ----------------------------------------------------------
 

@@ -237,7 +237,7 @@ def test_lod_cut_kernel_parity_with_vmapped_service_sweep(small_tree):
     # first frame ⇒ every slab freshly swept by the vmapped XLA path
     cut, _ = ls.batched_temporal_search(small_tree, states, cams,
                                         jnp.float32(FOCAL), jnp.asarray(taus))
-    _top, rpe, _stale = ls.batched_top_and_staleness(
+    _top, rpe, _stale, _causes = ls.batched_top_and_staleness(
         small_tree, states, cams, jnp.float32(FOCAL), jnp.asarray(taus))
     for i in range(b):
         cut_p, rexp_p, _rho = ops.lod_slab_sweep(
