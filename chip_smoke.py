@@ -303,9 +303,8 @@ def pallas_phase(city, tree, *, n_clients: int = 4, syncs: int = 3,
           "the Pallas sweep did not compile to a TPU kernel")
     got = lod_cut.lod_pair_sweep_pallas(*args)
     want = ls.sweep_slab_camera_pairs(
-        tables.mu, tables.size, tables.parent, tables.level, tables.is_leaf,
-        tables.valid, rpe, cams, jnp.float32(FOCAL), jnp.float32(TAU),
-        tree.meta.slab_max_depth)
+        tables.mu, tables.size, tables.end, tables.is_leaf, tables.valid,
+        rpe, cams, jnp.float32(FOCAL), jnp.float32(TAU))
     got, want = ([np.asarray(x) for x in out] for out in (got, want))
     _assert_same(got[:2], want[:2], "pallas kernel vs XLA sweep (cut)")
     finite = np.isfinite(want[2])

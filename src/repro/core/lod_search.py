@@ -7,9 +7,10 @@ Semantics (identical to HierGS-style traversal):
 
 *Fully-streaming traversal* — the tree is laid out as a replicated top-tree
 plus fixed-size subtree slabs (see lod_tree.py). One frame = a level-major
-sweep of the top-tree + a vmapped level-synchronous sweep of each slab. All
-memory access is regular; the only gathers are slab-local (VMEM-resident by
-construction) — the TPU analogue of the paper's shared-memory streaming.
+sweep of the top-tree + a vmapped sweep of each slab. Slabs are in DFS
+preorder, so a slab sweep reads ancestry from subtree ranges with one
+prefix max along the slab (no gather, no loop over levels) — the TPU
+analogue of the paper's shared-memory streaming.
 
 *Temporal-aware search* — per subtree we maintain a provably-safe reuse bound:
 after sweeping subtree s at camera position c0, ρ_s = min over its nodes of
@@ -38,7 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.lod_tree import LodTree, slab_subtree_end
+from repro.core.lod_tree import LodTree
 from repro.serve import tracing
 
 _EPS_DIST = 1e-6
@@ -115,11 +116,9 @@ class SlabTables:
 
     mu: jax.Array        # (Ns, S, 3)
     size: jax.Array      # (Ns, S)
-    parent: jax.Array    # (Ns, S) int32
-    level: jax.Array     # (Ns, S) int32
     is_leaf: jax.Array   # (Ns, S) bool
     valid: jax.Array     # (Ns, S) bool
-    end: jax.Array       # (Ns, S) int32 — DFS subtree end (Pallas sweep)
+    end: jax.Array       # (Ns, S) int32 — DFS subtree end
 
     @staticmethod
     def from_tree(tree: LodTree, mesh=None) -> "SlabTables":
@@ -129,9 +128,8 @@ class SlabTables:
         (or no mesh) replicates: bitwise the single-device tables."""
         tables = SlabTables(
             mu=tree.slab_mu(), size=tree.slab_size(),
-            parent=tree.slab_parent, level=tree.slab_level,
             is_leaf=tree.slab_is_leaf, valid=tree.slab_valid,
-            end=jnp.asarray(slab_subtree_end(tree)))
+            end=tree.slab_end)
         if mesh is not None:
             from repro.sharding.fleet import shard_slab_tables
             tables = shard_slab_tables(mesh, tables)
@@ -184,22 +182,26 @@ def top_sweep(tree: LodTree, cam_pos: jax.Array, focal, tau
     return expand, in_cut
 
 
-def _slab_sweep_one(mu, size, parent, level, is_leaf, valid, root_parent_expand,
-                    cam_pos, focal, tau, max_depth: int):
-    """Sweep a single (S,)-slab. Returns (in_cut, root_expand, rho)."""
+def _slab_sweep_one(mu, size, end, is_leaf, valid, root_parent_expand,
+                    cam_pos, focal, tau):
+    """Sweep a single (S,)-slab. Returns (in_cut, root_expand, rho).
+
+    The slab is in DFS preorder, so node j's subtree is the lane range
+    [j, end[j]) and j's ancestors are the valid nodes a < j with
+    end[a] > j. j's parent expands iff the slab root's parent does and no
+    ancestor stops (proj ≤ τ), i.e. iff the exclusive prefix max of
+    end[a]·[valid(a) ∧ ¬gt(a)] over a < j is at most j: one cumulative max
+    along the slab instead of a parent gather per level. The Pallas kernel
+    (repro.kernels.lod_cut) computes the same prefix max; the level loop it
+    replaces is the oracle `repro.kernels.ref.ref_lod_pair_sweep`."""
     dist2 = sq_dist(mu, cam_pos)
     gt = lod_gt(size, dist2, focal, tau)
 
-    s = mu.shape[0]
-    expand = jnp.zeros((s,), bool)
-    pexp = jnp.zeros((s,), bool)
-    for l in range(max_depth + 1):
-        at = level == l
-        pe_l = jnp.where(parent < 0, root_parent_expand,
-                         expand[jnp.clip(parent, 0, s - 1)])
-        pexp = jnp.where(at, pe_l, pexp)
-        expand = jnp.where(at, pe_l & gt, expand)
-    expand = expand & valid
+    lane = jnp.arange(mu.shape[0], dtype=end.dtype)
+    stop = jnp.where(valid & ~gt, end, 0)
+    reach = jax.lax.cummax(jnp.pad(stop[:-1], (1, 0)))   # exclusive
+    pexp = root_parent_expand & (reach <= lane)
+    expand = pexp & gt & valid
     in_cut = pexp & (~gt | is_leaf) & valid
 
     # bit-accurate reuse bound: min distance-to-LoD-boundary over valid nodes
@@ -213,11 +215,10 @@ def _slab_sweep_one(mu, size, parent, level, is_leaf, valid, root_parent_expand,
 
 
 def _slab_sweep_all(tree: LodTree, cam_pos, focal, tau, root_parent_expand):
-    fn = functools.partial(_slab_sweep_one, cam_pos=cam_pos, focal=focal, tau=tau,
-                           max_depth=tree.meta.slab_max_depth)
+    fn = functools.partial(_slab_sweep_one, cam_pos=cam_pos, focal=focal, tau=tau)
     return jax.vmap(fn)(
-        tree.slab_mu(), tree.slab_size(), tree.slab_parent, tree.slab_level,
-        tree.slab_is_leaf, tree.slab_valid, root_parent_expand)
+        tree.slab_mu(), tree.slab_size(), tree.slab_end, tree.slab_is_leaf,
+        tree.slab_valid, root_parent_expand)
 
 
 def _root_parent_expand(tree: LodTree, top_expand: jax.Array) -> jax.Array:
@@ -342,13 +343,12 @@ def pow2_bucket(n: int, cap: int) -> int:
     return max(1, min(b, cap))
 
 
-@functools.partial(jax.jit, static_argnames=("max_depth",))
-def _sweep_selected(slab_mu, slab_size, slab_parent, slab_level, slab_is_leaf,
-                    slab_valid, rpe_sel, cam_pos, focal, tau, max_depth: int):
-    fn = functools.partial(_slab_sweep_one, cam_pos=cam_pos, focal=focal, tau=tau,
-                           max_depth=max_depth)
-    return jax.vmap(fn)(slab_mu, slab_size, slab_parent, slab_level,
-                        slab_is_leaf, slab_valid, rpe_sel)
+@functools.partial(jax.jit, static_argnames=())
+def _sweep_selected(slab_mu, slab_size, slab_end, slab_is_leaf, slab_valid,
+                    rpe_sel, cam_pos, focal, tau):
+    fn = functools.partial(_slab_sweep_one, cam_pos=cam_pos, focal=focal, tau=tau)
+    return jax.vmap(fn)(slab_mu, slab_size, slab_end, slab_is_leaf,
+                        slab_valid, rpe_sel)
 
 
 def _top_and_terms(tree: LodTree, state: TemporalState, cam_pos, focal, tau):
@@ -447,10 +447,9 @@ def predicted_stale_counts(tree: LodTree, states: TemporalState,
     return stale.sum(axis=1).astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("max_depth",))
-def sweep_slab_camera_pairs(slab_mu, slab_size, slab_parent, slab_level,
-                            slab_is_leaf, slab_valid, rpe_sel, cam_sel,
-                            focal, tau, max_depth: int):
+@functools.partial(jax.jit, static_argnames=())
+def sweep_slab_camera_pairs(slab_mu, slab_size, slab_end, slab_is_leaf,
+                            slab_valid, rpe_sel, cam_sel, focal, tau):
     """Sweep K (slab, camera) pairs in one vmapped program.
 
     Unlike `_sweep_selected` (one shared camera), every pair carries its own
@@ -462,12 +461,12 @@ def sweep_slab_camera_pairs(slab_mu, slab_size, slab_parent, slab_level,
     k = slab_size.shape[0]
     taus = jnp.broadcast_to(jnp.asarray(tau, jnp.float32), (k,))
 
-    def fn(mu, size, parent, level, leaf, valid, rpe, cam, tau_k):
-        return _slab_sweep_one(mu, size, parent, level, leaf, valid, rpe,
-                               cam, focal, tau_k, max_depth=max_depth)
+    def fn(mu, size, end, leaf, valid, rpe, cam, tau_k):
+        return _slab_sweep_one(mu, size, end, leaf, valid, rpe, cam, focal,
+                               tau_k)
 
-    return jax.vmap(fn)(slab_mu, slab_size, slab_parent, slab_level,
-                        slab_is_leaf, slab_valid, rpe_sel, cam_sel, taus)
+    return jax.vmap(fn)(slab_mu, slab_size, slab_end, slab_is_leaf,
+                        slab_valid, rpe_sel, cam_sel, taus)
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
@@ -505,10 +504,9 @@ def temporal_search_hybrid(tree: LodTree, state: TemporalState, cam_pos,
         pad = np.resize(idx, bucket)  # repeat-pad; duplicates are harmless
         sel = jnp.asarray(pad)
         f_cut, f_rexp, f_rho = _sweep_selected(
-            tree.slab_mu()[sel], tree.slab_size()[sel], tree.slab_parent[sel],
-            tree.slab_level[sel], tree.slab_is_leaf[sel], tree.slab_valid[sel],
-            rpe[sel], cam_pos, jnp.float32(focal), jnp.float32(tau),
-            tree.meta.slab_max_depth)
+            tree.slab_mu()[sel], tree.slab_size()[sel], tree.slab_end[sel],
+            tree.slab_is_leaf[sel], tree.slab_valid[sel], rpe[sel], cam_pos,
+            jnp.float32(focal), jnp.float32(tau))
         slab_cut, root_expand, rho, cam0 = _apply_slab_updates(
             slab_cut, root_expand, rho, cam0, sel, f_cut, f_rexp, f_rho,
             cam_pos)

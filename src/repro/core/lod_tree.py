@@ -63,6 +63,9 @@ class LodTree:
     slab_parent: (Ns, S) slab-local parent index (-1 for the slab root).
     slab_is_leaf, slab_valid: (Ns, S) bool.
     slab_level: (Ns, S) int32 level inside the slab (root = 0; padding = big).
+    slab_end:  (Ns, S) int32 DFS subtree end: node j's subtree is the slab
+               range [j, slab_end[j]) (0 on padding) — what the slab sweeps
+               read ancestry from (`subtree_end`).
     slab_root_parent_top: (Ns,) index into top-tree of each slab root's parent.
     meta: TreeMeta (static).
     """
@@ -75,6 +78,7 @@ class LodTree:
     slab_is_leaf: jax.Array
     slab_valid: jax.Array
     slab_level: jax.Array
+    slab_end: jax.Array
     slab_root_parent_top: jax.Array
     meta: TreeMeta = dataclasses.field(metadata=dict(static=True))
 
@@ -427,27 +431,31 @@ def build_lod_tree(
         slab_is_leaf=jnp.asarray(s_is_leaf),
         slab_valid=jnp.asarray(s_valid),
         slab_level=jnp.asarray(s_level),
+        slab_end=jnp.asarray(subtree_end(s_parent, s_level, s_valid,
+                                         slab_max_depth)),
         slab_root_parent_top=jnp.asarray(root_parent_top),
         meta=meta,
     )
 
 
-def slab_subtree_end(tree: LodTree) -> np.ndarray:
+def subtree_end(parent, level, valid, max_depth: int) -> np.ndarray:
     """(Ns, S) int32 — for every slab node j, one past the last node of its
-    subtree, so the subtree is the slab range [j, end[j]) (0 on padding).
+    subtree, so the subtree is the slab range [j, end[j]) (0 on padding),
+    from the (Ns, S) slab-local parents, levels and valid flags.
 
-    The Pallas slab sweep (repro.kernels.lod_cut) reads ancestry from these
-    ranges instead of gathering parents, which holds only for DFS-preorder
-    slabs — the layout `build_lod_tree` emits. The ranges are checked here:
-    every node lies inside its parent's range, and the ranges covering a
-    node are exactly its ancestors (their count equals its level)."""
-    parent = np.asarray(tree.slab_parent).astype(np.int64)
-    level = np.asarray(tree.slab_level).astype(np.int64)
-    valid = np.asarray(tree.slab_valid)
+    Every slab sweep (`lod_search`, `repro.kernels.lod_cut`) reads ancestry
+    from these ranges instead of gathering parents, which holds only for
+    DFS-preorder slabs — the layout `build_lod_tree` emits. The ranges are
+    checked here: every node lies inside its parent's range, and the ranges
+    covering a node are exactly its ancestors (their count equals its
+    level)."""
+    parent = np.asarray(parent).astype(np.int64)
+    level = np.asarray(level).astype(np.int64)
+    valid = np.asarray(valid)
     ns, s = parent.shape
     size = valid.astype(np.int64)
     base = (np.arange(ns) * s)[:, None]
-    for l in range(tree.meta.slab_max_depth, 0, -1):
+    for l in range(max_depth, 0, -1):
         at = valid & (level == l)
         size += np.bincount((base + parent)[at], weights=size[at],
                             minlength=ns * s).reshape(ns, s).astype(np.int64)

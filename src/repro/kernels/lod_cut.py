@@ -11,9 +11,10 @@ loop's parent gather (which Mosaic cannot lower across vregs) with a prefix
 max: node j's parent-expand bit is false iff some valid node a < j that does
 not expand on its own (proj ≤ τ) still covers it, i.e. iff
 max_{a<j} end[a]·[valid(a) ∧ ¬gt(a)] > j. The prefix max is log2(S) lane
-rotations. Results are bitwise those of the level-loop XLA sweep
-(`lod_search.sweep_slab_camera_pairs`). Also emits the per-subtree temporal
-reuse radius ρ."""
+rotations. Its twin is the XLA sweep (`lod_search.sweep_slab_camera_pairs`),
+which computes the same prefix max with a cumulative max; both are bitwise
+the level-loop oracle (`repro.kernels.ref.ref_lod_pair_sweep`) on the cut.
+Also emits the per-subtree temporal reuse radius ρ."""
 
 from __future__ import annotations
 
@@ -122,7 +123,7 @@ def lod_slab_sweep_pallas(slab_mu, slab_size, slab_end, slab_is_leaf,
     """Sweep all (Ns, S) slabs from ONE camera: the pair kernel with the
     camera and τ broadcast to every slab. Returns (in_cut (Ns,S) bool,
     root_expand (Ns,), rho (Ns,)); matches
-    repro.core.lod_search._slab_sweep_one bit-for-bit."""
+    repro.core.lod_search._slab_sweep_one bit-for-bit on the cut."""
     ns = slab_size.shape[0]
     cams = jnp.broadcast_to(jnp.asarray(cam_pos, jnp.float32).reshape(1, 3),
                             (ns, 3))

@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.binning import TileLists
-from repro.core.lod_tree import slab_subtree_end
 from repro.core.projection import Splats
 from repro.render.common import eye_views
 from repro.kernels import ref as kref
@@ -99,10 +98,9 @@ def lod_slab_sweep(tree, cam_pos, focal, tau, root_parent_expand, *,
                    use_pallas: bool = True, interpret=None):
     if use_pallas:
         return lod_slab_sweep_pallas(
-            tree.slab_mu(), tree.slab_size(),
-            jnp.asarray(slab_subtree_end(tree)), tree.slab_is_leaf,
-            tree.slab_valid, root_parent_expand, cam_pos, focal, tau,
-            interpret=interpret)
+            tree.slab_mu(), tree.slab_size(), tree.slab_end,
+            tree.slab_is_leaf, tree.slab_valid, root_parent_expand, cam_pos,
+            focal, tau, interpret=interpret)
     args = (tree.slab_mu(), tree.slab_size(), tree.slab_parent, tree.slab_level,
             tree.slab_is_leaf, tree.slab_valid, root_parent_expand)
     return kref.ref_lod_slab_sweep(*args, cam_pos, focal, tau,

@@ -2,12 +2,12 @@
 
 Where a core module already implements the math in pure jnp, the oracle
 reuses it (the core path is itself tested against independent references —
-e.g. raster vs the untiled per-pixel renderer, lod sweep vs the numpy
-level-iteration). Attention gets an independent naive softmax here."""
+e.g. raster vs the untiled per-pixel renderer). The LoD slab sweep's oracle
+is the level loop, kept here apart from the prefix max that both the XLA
+sweep and the Pallas kernel compute; attention gets an independent naive
+softmax."""
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -64,13 +64,61 @@ def ref_rasterize(entries: jax.Array, counts: jax.Array, *, tile: int,
                                eps_t=eps_t)
 
 
+def _level_loop_sweep(mu, size, parent, level, is_leaf, valid,
+                      root_parent_expand, cam_pos, focal, tau, max_depth: int):
+    """One (S,)-slab swept level by level: each level reads its parents'
+    expand bits with a gather, as the traversal's definition states
+    (expand(n) = expand(parent(n)) ∧ proj(n) > τ). Independent of the DFS
+    subtree ranges the served sweeps read. Returns (in_cut, root_expand,
+    rho)."""
+    gt = _ls.lod_gt(size, _ls.sq_dist(mu, cam_pos), focal, tau)
+    s = mu.shape[0]
+    expand = jnp.zeros((s,), bool)
+    pexp = jnp.zeros((s,), bool)
+    for l in range(max_depth + 1):
+        at = level == l
+        pe_l = jnp.where(parent < 0, root_parent_expand,
+                         expand[jnp.clip(parent, 0, s - 1)])
+        pexp = jnp.where(at, pe_l, pexp)
+        expand = jnp.where(at, pe_l & gt, expand)
+    expand = expand & valid
+    in_cut = pexp & (~gt | is_leaf) & valid
+
+    rstar = size * focal / tau
+    dist = jnp.linalg.norm(mu - cam_pos, axis=-1)
+    margin = jnp.where(valid, jnp.abs(dist - rstar), jnp.inf)
+    return in_cut, expand[0], jnp.min(margin)
+
+
+def ref_lod_pair_sweep(pair_mu, pair_size, pair_parent, pair_level,
+                       pair_is_leaf, pair_valid, root_parent_expand, cam_pos,
+                       focal, tau, *, max_depth: int):
+    """Oracle for the pair sweeps (`lod_search.sweep_slab_camera_pairs`,
+    lod_cut.lod_pair_sweep_pallas): K (slab, camera) pairs, each with its
+    own (K, 3) camera and a scalar or (K,) τ, swept by the level loop from
+    slab-local parents and levels. Returns (in_cut (K,S), root_expand (K,),
+    rho (K,))."""
+    k = pair_size.shape[0]
+    taus = jnp.broadcast_to(jnp.asarray(tau, jnp.float32), (k,))
+
+    def fn(mu, size, parent, level, leaf, valid, rpe, cam, tau_k):
+        return _level_loop_sweep(mu, size, parent, level, leaf, valid, rpe,
+                                 cam, focal, tau_k, max_depth)
+
+    return jax.vmap(fn)(pair_mu, pair_size, pair_parent, pair_level,
+                        pair_is_leaf, pair_valid, root_parent_expand,
+                        jnp.asarray(cam_pos, jnp.float32), taus)
+
+
 def ref_lod_slab_sweep(slab_mu, slab_size, slab_parent, slab_level,
                        slab_is_leaf, slab_valid, root_parent_expand,
                        cam_pos, focal, tau, *, max_depth: int):
-    fn = functools.partial(_ls._slab_sweep_one, cam_pos=jnp.asarray(cam_pos, jnp.float32),
-                           focal=focal, tau=tau, max_depth=max_depth)
-    return jax.vmap(fn)(slab_mu, slab_size, slab_parent, slab_level,
-                        slab_is_leaf, slab_valid, root_parent_expand)
+    """`ref_lod_pair_sweep` of every slab from one camera."""
+    cams = jnp.broadcast_to(jnp.asarray(cam_pos, jnp.float32).reshape(1, 3),
+                            (slab_size.shape[0], 3))
+    return ref_lod_pair_sweep(slab_mu, slab_size, slab_parent, slab_level,
+                              slab_is_leaf, slab_valid, root_parent_expand,
+                              cams, focal, tau, max_depth=max_depth)
 
 
 def ref_stereo_merge(src_ranks: jax.Array, src_ids: jax.Array):

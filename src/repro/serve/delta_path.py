@@ -53,7 +53,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Tuple
+from typing import Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -221,7 +221,8 @@ def _union_refs(wanted: jax.Array, by_rank: jax.Array, rank_of: jax.Array,
 def build_delta_batch(gaussians: Gaussians, codec: comp.Codec,
                       delta_masks: jax.Array, budget: int,
                       active=None, mesh=None, *, pending=None, priority=None,
-                      allowance=None, page_size=None) -> DeltaBatch:
+                      allowance=None, page_size=None,
+                      widths: Sequence[int] = ()) -> DeltaBatch:
     """Encode one sync's fleet Δcut once, paged under the budget.
 
     delta_masks: (B, N) bool — the batched `SyncPlan.delta_data`.
@@ -248,7 +249,11 @@ def build_delta_batch(gaussians: Gaussians, codec: comp.Codec,
     await — the same bounded-recompilation pattern as the pooled stale-slab
     scheduler), so codec quantize/pack FLOPs track the sync's unique
     Gaussians, not the static budget: a steady-state sync with a tiny union
-    encodes a tiny bucket, never the whole budget.
+    encodes a tiny bucket, never the whole budget. `widths` lists the widths
+    a long-lived service has built: the stream takes the narrowest of them
+    (up to the budget) that holds the bucket, so a draining backlog stays on
+    compiled programs, and only a union wider than each builds a new width.
+    A wider stream only pads: every row, reference and byte is the same.
 
     Sharded fleets (`mesh`, repro.sharding.fleet): the union `any` over
     clients is a CROSS-SHARD reduction — the union mask, its gids, and the
@@ -270,6 +275,7 @@ def build_delta_batch(gaussians: Gaussians, codec: comp.Codec,
     n_union, key = _union_mask(wanted, priority)
     with tracing.span("delta.union_size_read"):
         width = ls.pow2_bucket(int(jax.device_get(n_union)), budget)
+    width = min((w for w in widths if width <= w <= budget), default=width)
     allow = (jnp.full((b,), width, jnp.int32) if allowance is None
              else jnp.asarray(allowance, jnp.int32))
     psize = width if page_size is None else max(1, min(int(page_size), width))

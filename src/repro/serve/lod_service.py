@@ -329,7 +329,7 @@ def _finish_sync(tree: LodTree, cfg: SessionConfig, state: ServiceState,
                  dedup: bool = False, delta_budget: Optional[int] = None,
                  priority=None, allowance=None,
                  page_size: Optional[int] = None,
-                 participate=None,
+                 participate=None, widths=(),
                  mesh=None) -> Tuple[ServiceState, ServiceStats,
                                      Optional[dp.DeltaBatch]]:
     """Shared tail of both sync paths: batched management-table update,
@@ -351,7 +351,8 @@ def _finish_sync(tree: LodTree, cfg: SessionConfig, state: ServiceState,
     tree's `node_levels()`, computed here when not supplied — long-lived
     services pass their cached copy); `allowance` the optional (B,) int32
     per-client row cap (the bitrate controller's knob); `page_size` the
-    priority-page granularity (default: one page per stream).
+    priority-page granularity (default: one page per stream); `widths`
+    the stream widths already built (`dp.build_delta_batch`).
 
     Ragged fleets: inactive slots (per `state.fleet.active`) are masked out
     of EVERYTHING here — cut masks (⇒ no Δ rows, no cut ids, fresh -1 cut
@@ -404,7 +405,8 @@ def _finish_sync(tree: LodTree, cfg: SessionConfig, state: ServiceState,
         batch = dp.build_delta_batch(tree.gaussians, codec, plan.delta_data,
                                      delta_budget, active=eff, mesh=mesh,
                                      pending=state.pending, priority=priority,
-                                     allowance=allowance, page_size=page_size)
+                                     allowance=allowance, page_size=page_size,
+                                     widths=widths)
         sync_bytes = mgr.batched_wire_bytes(plan, bytes_per_g,
                                             shared_payload=True,
                                             active=eff,
@@ -569,7 +571,7 @@ def service_sync_vmapped(tree: LodTree, cfg: SessionConfig,
                          delta_budget: Optional[int] = None,
                          priority=None, allowance=None,
                          page_size: Optional[int] = None,
-                         participate=None,
+                         participate=None, widths=(),
                          mesh=None) -> Tuple[ServiceState, ServiceStats,
                                              Optional[dp.DeltaBatch]]:
     """One LoD sync for every client, fully on-device (vmapped search).
@@ -608,7 +610,8 @@ def service_sync_vmapped(tree: LodTree, cfg: SessionConfig,
                         bytes_per_g, codec=codec, dedup=dedup,
                         delta_budget=delta_budget, priority=priority,
                         allowance=allowance, page_size=page_size,
-                        participate=participate, mesh=mesh)
+                        participate=participate, widths=widths,
+                        mesh=mesh)
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3),
@@ -698,15 +701,18 @@ def _compact_stale_pairs(stale: jax.Array, bucket: int, n_shards: int = 1,
     return sel_b, sel_s, valid
 
 
-@functools.partial(jax.jit, static_argnames=("max_depth", "impl", "mesh"))
+@functools.partial(jax.jit, static_argnames=("impl", "mesh"))
 @tracing.scoped("lod.pair_sweep")
 def _pooled_pair_sweep(tables: ls.SlabTables, rpe, cams, taus, sel_b, sel_s,
-                       focal, *, max_depth: int, impl: str, mesh=None):
-    """Gather the pooled pairs' slab attributes from the device-resident
-    tables and sweep them — ONE fused program (the gathers never detour
-    through the host). `impl` picks the vmapped XLA sweep or the Pallas
-    lod-cut kernel (`repro.kernels.lod_cut.lod_pair_sweep_pallas`, compiled
-    on a TPU and interpreted on the CPU).
+                       focal, *, impl: str, mesh=None):
+    """Gather the pooled pairs' slab attributes (means, sizes, DFS subtree
+    ends, leaf and valid flags) from the device-resident tables and sweep
+    them — ONE fused program (the gathers never detour through the host).
+    `impl` picks the vmapped XLA sweep (`lod_search.sweep_slab_camera_pairs`)
+    or the Pallas lod-cut kernel (`repro.kernels.lod_cut.lod_pair_sweep_pallas`,
+    compiled on a TPU and interpreted on the CPU): twins that compute the
+    same prefix max over subtree ends, bitwise equal on the cut, and both
+    checked against the level-loop oracle (`repro.kernels.ref`).
 
     Sharded fleets: the pair axis is constrained onto the `clients` axis
     (each shard's bucket lanes sweep on that shard); the slab-table gathers
@@ -726,8 +732,7 @@ def _pooled_pair_sweep(tables: ls.SlabTables, rpe, cams, taus, sel_b, sel_s,
         return lc.lod_pair_sweep_pallas(*gathered, focal, tau_sel)
     with tracing.scope("lod.pair_sweep/gather"):
         tau_sel = taus[sel_b]
-        gathered = (tables.mu[sel_s], tables.size[sel_s],
-                    tables.parent[sel_s], tables.level[sel_s],
+        gathered = (tables.mu[sel_s], tables.size[sel_s], tables.end[sel_s],
                     tables.is_leaf[sel_s], tables.valid[sel_s],
                     rpe[sel_b, sel_s], cams[sel_b])
     if mesh is not None:
@@ -735,8 +740,7 @@ def _pooled_pair_sweep(tables: ls.SlabTables, rpe, cams, taus, sel_b, sel_s,
             g, ("clients",) + (None,) * (g.ndim - 1), mesh)
             for g in gathered)
         tau_sel = shd.constrain_fleet(tau_sel, ("clients",), mesh)
-    return ls.sweep_slab_camera_pairs(*gathered, focal, tau_sel,
-                                      max_depth)
+    return ls.sweep_slab_camera_pairs(*gathered, focal, tau_sel)
 
 
 def service_sync_pooled(tree: LodTree, cfg: SessionConfig,
@@ -747,7 +751,7 @@ def service_sync_pooled(tree: LodTree, cfg: SessionConfig,
                         delta_budget: Optional[int] = None,
                         priority=None, allowance=None,
                         page_size: Optional[int] = None,
-                        participate=None,
+                        participate=None, widths=(),
                         tables: Optional[ls.SlabTables] = None,
                         sweep_impl: str = "xla",
                         mesh=None, account: Optional[dict] = None
@@ -849,7 +853,7 @@ def service_sync_pooled(tree: LodTree, cfg: SessionConfig,
                                                    n_shards=k, mesh=mesh)
         f_cut, f_rexp, f_rho = _pooled_pair_sweep(
             tables, rpe, cams, tau_b, sel_b, sel_s, jnp.float32(focal),
-            max_depth=m.slab_max_depth, impl=sweep_impl, mesh=mesh)
+            impl=sweep_impl, mesh=mesh)
         slab_cut, root_expand, rho, cam0 = _apply_pooled_updates(
             slab_cut, root_expand, rho, cam0, sel_b, sel_s,
             f_cut, f_rexp, f_rho, cams[sel_b], valid, guard=k > 1,
@@ -877,7 +881,7 @@ def service_sync_pooled(tree: LodTree, cfg: SessionConfig,
                         dedup=dedup, delta_budget=delta_budget,
                         priority=priority, allowance=allowance,
                         page_size=page_size, participate=participate,
-                        mesh=mesh)
+                        widths=widths, mesh=mesh)
 
 
 # ---------------------------------------------------------------------------
@@ -962,7 +966,9 @@ class LodService:
     Δ-union exceeds `delta_budget` ships the coarsest `page_size`-row
     priority pages now and carries the rest as per-slot debt
     (`ServiceState.pending`) — every Gaussian arrives within ⌈U/width⌉
-    syncs, nothing is silently lost. `bandwidth` turns on the closed-loop
+    syncs, nothing is silently lost. As a backlog drains, the stream's
+    width falls only to widths this service has built: a narrower one
+    would compile anew on a served tick. `bandwidth` turns on the closed-loop
     per-client bitrate controller: pass a `BANDWIDTH_TIERS` name ("phone" /
     "headset" / "tethered"), a bytes-per-sync number, or a per-client
     sequence of either; each sync, the PREVIOUS sync's measured per-client
@@ -1091,6 +1097,10 @@ class LodService:
                                     capacity=self.capacity))
         self.last_delta: Optional[dp.DeltaBatch] = None
         self._delta_ids = np.full(self.capacity, -1, np.int64)
+        # Δ stream widths built so far: a sync takes the narrowest that
+        # holds its union, so a backlog that drains keeps the programs it
+        # has and never compiles a narrower width on a served tick
+        self._union_widths: set = set()
         # syncs run so far: the `tick` of every trace span of the next sync
         self.syncs = 0
         # what the last pooled sync decided (`service_sync_pooled`'s
@@ -1508,7 +1518,8 @@ class LodService:
         kw = dict(taus=taus_eff, codec=self.codec, dedup=self.dedup,
                   delta_budget=self.delta_budget, priority=self._priority,
                   allowance=allowance, page_size=self.page_size,
-                  participate=part_mask, mesh=self.mesh)
+                  participate=part_mask, widths=sorted(self._union_widths),
+                  mesh=self.mesh)
         self.last_account = {}
         if self.mode == "pooled":
             self.state, stats, batch = service_sync_pooled(
@@ -1521,6 +1532,7 @@ class LodService:
                 self.bytes_per_g, **kw)
         if batch is not None:
             self.last_delta = batch
+            self._union_widths.add(int(batch.union_gids.shape[0]))
             # tenancy snapshot: which client each slot's ref_mask row is FOR
             # (guards client_delta against churn between sync and decode)
             self._delta_ids = self._client_ids.copy()

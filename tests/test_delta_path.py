@@ -6,6 +6,7 @@ encode per sync, and fleet bytes must grow with unique Gaussians, not B."""
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import compression as comp
@@ -164,6 +165,56 @@ def test_union_ranking_compiles_once_across_stream_widths(small_tree):
         traced = traced or dp._rank_union._cache_size()
     assert widths == {64, 256, 1024}
     assert dp._rank_union._cache_size() == traced
+
+
+@pytest.mark.parametrize("budget,widths,page", [
+    (4096, (), None),              # nothing built: the union's own bucket
+    (4096, (16,), None),           # every built width too narrow: new bucket
+    (4096, (2048, 4096), None),    # the narrowest built width that holds it
+    (4096, (4096, 1024), 64),      # priority pages, widths in any order
+    (4096, (1 << 20,), 64),        # a built width above the budget: unused
+    (128, (1024,), 32),            # paged overflow: the budget caps both
+])
+def test_built_widths_only_pad_the_stream(small_tree, budget, widths, page):
+    """A stream widened to a width a service has built only pads it: every
+    shipped row, reference, deferral, page count and decoded row is the
+    unpadded stream's, and the width is the narrowest built width within
+    the budget that holds the union's pow2 bucket, else that bucket."""
+    rng = np.random.default_rng(13)
+    masks = jnp.asarray(_masks_for_overlap(small_tree.n_pad, 3, 0.5, rng))
+    codec, _ = session_wire_format(small_tree, SessionConfig(tau=TAU))
+    sh_k = small_tree.gaussians.sh.shape[1]
+    kw = dict(priority=small_tree.node_levels(), page_size=page)
+    base = dp.build_delta_batch(small_tree.gaussians, codec, masks, budget,
+                                **kw)
+    wide = dp.build_delta_batch(small_tree.gaussians, codec, masks, budget,
+                                widths=widths, **kw)
+    u = base.union_gids.shape[0]
+    assert wide.union_gids.shape[0] == min(
+        [w for w in widths if u <= w <= budget], default=u)
+    for name in ("n_union", "n_shipped", "delivered", "deferred",
+                 "client_overflow", "client_pages", "pages", "overflow"):
+        np.testing.assert_array_equal(np.asarray(getattr(wide, name)),
+                                      np.asarray(getattr(base, name)),
+                                      err_msg=name)
+    gids = np.asarray(wide.union_gids)
+    np.testing.assert_array_equal(gids[:u], np.asarray(base.union_gids))
+    assert (gids[u:] == -1).all()
+    ref = np.asarray(wide.ref_mask)
+    np.testing.assert_array_equal(ref[:, :u], np.asarray(base.ref_mask))
+    assert not ref[:, u:].any()
+    np.testing.assert_array_equal(np.asarray(wide.row_page)[:u],
+                                  np.asarray(base.row_page))
+    for c in range(masks.shape[0]):
+        ids_b, rows_b = dp.decode_client(codec, base, sh_k, c)
+        ids_w, rows_w = dp.decode_client(codec, wide, sh_k, c)
+        ids_b, ids_w = np.asarray(ids_b), np.asarray(ids_w)
+        np.testing.assert_array_equal(ids_w[:u], ids_b)
+        on = ids_b >= 0
+        for leaf_b, leaf_w in zip(jax.tree_util.tree_leaves(rows_b),
+                                  jax.tree_util.tree_leaves(rows_w)):
+            np.testing.assert_array_equal(np.asarray(leaf_w)[:u][on],
+                                          np.asarray(leaf_b)[on])
 
 
 def test_first_owner_counts_partition_union(small_tree):

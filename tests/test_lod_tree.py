@@ -1,13 +1,11 @@
 """LoD tree construction invariants."""
 
-import dataclasses
 
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.gaussians import random_gaussians
-from repro.core.lod_tree import build_lod_tree, slab_subtree_end
+from repro.core.lod_tree import build_lod_tree, subtree_end
 from repro.core.lod_search import global_level_np, global_parent_np
 
 
@@ -85,9 +83,12 @@ def _subtree_end_brute(tree):
 @pytest.mark.parametrize("fixture", ["small_tree", "tiny_tree"])
 def test_slabs_are_dfs_preorder(fixture, request):
     """Each node's subtree is the slab range [j, end[j]) — the layout the
-    Pallas sweep reads ancestry from."""
+    slab sweeps read ancestry from, as the tree carries it (`slab_end`)."""
     tree = request.getfixturevalue(fixture)
-    np.testing.assert_array_equal(slab_subtree_end(tree),
+    np.testing.assert_array_equal(
+        subtree_end(tree.slab_parent, tree.slab_level, tree.slab_valid,
+                    tree.meta.slab_max_depth), _subtree_end_brute(tree))
+    np.testing.assert_array_equal(np.asarray(tree.slab_end),
                                   _subtree_end_brute(tree))
 
 
@@ -105,8 +106,5 @@ def test_subtree_end_rejects_level_order_slabs(small_tree):
         p = parent[s][order]
         new_p[s] = np.where(p >= 0, inv[np.clip(p, 0, None)], -1)
         new_l[s], new_v[s] = level[s][order], valid[s][order]
-    bfs = dataclasses.replace(small_tree, slab_parent=jnp.asarray(new_p),
-                              slab_level=jnp.asarray(new_l),
-                              slab_valid=jnp.asarray(new_v))
     with pytest.raises(ValueError, match="DFS preorder"):
-        slab_subtree_end(bfs)
+        subtree_end(new_p, new_l, new_v, small_tree.meta.slab_max_depth)

@@ -3,6 +3,7 @@ cross-client pooled scheduler, and the functional session core."""
 
 import dataclasses as dc
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -13,6 +14,7 @@ from repro.core.camera import StereoRig, make_camera
 from repro.core.pipeline import (CollaborativeSession, SessionConfig,
                                  cloud_sync_step, idle_step, session_init,
                                  session_step, session_wire_format)
+from repro.serve import delta_path as dp
 from repro.serve import lod_service as svc
 
 FOCAL = 1400.0
@@ -249,6 +251,90 @@ def test_pallas_sweep_impl_bit_parity(small_tree):
                 err_msg=f"{f} {name}")
     with pytest.raises(ValueError):
         mk(mode="vmapped", sweep_impl="pallas")
+
+
+# the event JAX reports for every executable it builds, which the benchmark
+# counts as `compiles_in_window`
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def test_pooled_ticks_at_a_known_bucket_compile_nothing(small_tree):
+    """Once a pooled service has met a pair bucket and a Δ-union width, two
+    more ticks at the same bucket and width build no executable: the pair
+    sweep (and every other program of the tick) compiles once per pow2
+    bucket, never inside a steady window."""
+    cfg = SessionConfig(tau=TAU, cut_budget=4096)
+    service = svc.LodService(small_tree, cfg, 3, focal=FOCAL, mode="pooled")
+    here = np.asarray([[30, 30, 2], [40, 32, 3], [26, 44, 2]], np.float32)
+    there = here + np.float32([14.0, -10.0, 1.0])
+
+    def tick(cams):
+        service.sync(cams)
+        return (service.last_account["lanes"],
+                service.last_delta.union_gids.shape[0])
+
+    warm = [tick(c) for c in (here, there, here, there)]
+    built = []
+
+    def listen(event, duration, fun_name="", **_):
+        if event == COMPILE_EVENT:
+            built.append(fun_name)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        steady = [tick(c) for c in (here, there)]
+        jax.block_until_ready(service.state)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert steady == warm[2:], "the ticks met a new bucket or union width"
+    assert all(lanes > 0 for lanes, _ in steady), "no pair was swept"
+    assert built == []
+
+
+def test_draining_union_reuses_built_widths(small_tree):
+    """A coarse first sync builds a narrow Δ stream, a street-level sync a
+    wide one; small moves then drain the union below the narrow width. The
+    service goes back to the narrowest stream it has built that holds the
+    union, so no narrower width compiles and the wide one is not kept, and
+    it serves bitwise what a twin that sizes each stream to its own union
+    serves (cuts, stats, every client's ids)."""
+    cfg = SessionConfig(tau=TAU, cut_budget=4096)
+    # a short focal length keeps the cut coarse, so each 6 m walk brings
+    # new Δ rows (at FOCAL the first sync ships every leaf)
+    service = svc.LodService(small_tree, cfg, 3, focal=300.0, mode="pooled")
+    twin = svc.LodService(small_tree, cfg, 3, focal=300.0, mode="pooled")
+    here = np.asarray([[30, 30, 2], [40, 32, 3], [26, 44, 2]], np.float32)
+    high = here.copy()
+    high[:, 2] = 160.0
+    walk = [high] + [here + np.float32([6.0, 0.0, 0.0]) * k
+                     for k in range(4)]
+    widths, unions, refs_built = [], [], []
+    for cams in walk:
+        twin._union_widths.clear()      # the twin never keeps a width
+        before = dp._union_refs._cache_size()
+        st_s = service.sync(cams)
+        refs_built.append(dp._union_refs._cache_size() - before)
+        st_t = twin.sync(cams)
+        widths.append(service.last_delta.union_gids.shape[0])
+        unions.append(int(service.last_delta.n_union))
+        np.testing.assert_array_equal(np.asarray(service.state.cut_gids),
+                                      np.asarray(twin.state.cut_gids))
+        for a, b in zip(jax.tree_util.tree_leaves(st_s),
+                        jax.tree_util.tree_leaves(st_t)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        u = twin.last_delta.union_gids.shape[0]
+        for cid in service.active_ids:
+            ids_s = np.asarray(service.client_delta(cid)[0])
+            np.testing.assert_array_equal(
+                ids_s[:u], np.asarray(twin.client_delta(cid)[0]))
+            assert (ids_s[u:] == -1).all()
+    narrow, wide = widths[0], widths[1]
+    assert narrow < wide, "the street-level sync built no wider stream"
+    assert min(unions[2:]) > 0, "a walk brought no Δ row"
+    assert all(ls.pow2_bucket(u, service.delta_budget) < narrow
+               for u in unions[2:]), "the union never drained"
+    assert widths[2:] == [narrow] * 3
+    assert refs_built[2:] == [0] * 3, "a drained sync built a width"
 
 
 def test_service_dedup_client_payload_roundtrip(small_tree):

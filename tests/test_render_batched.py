@@ -252,10 +252,10 @@ def test_lod_cut_kernel_parity_with_vmapped_service_sweep(small_tree):
     sel_s = np.tile(np.arange(m.Ns), b)
     f_cut, f_rexp, _f_rho = ls.sweep_slab_camera_pairs(
         small_tree.slab_mu()[sel_s], small_tree.slab_size()[sel_s],
-        small_tree.slab_parent[sel_s], small_tree.slab_level[sel_s],
-        small_tree.slab_is_leaf[sel_s], small_tree.slab_valid[sel_s],
-        rpe[sel_b, sel_s], jnp.asarray(cams)[sel_b],
-        jnp.float32(FOCAL), jnp.asarray(taus)[sel_b], m.slab_max_depth)
+        small_tree.slab_end[sel_s], small_tree.slab_is_leaf[sel_s],
+        small_tree.slab_valid[sel_s], rpe[sel_b, sel_s],
+        jnp.asarray(cams)[sel_b], jnp.float32(FOCAL),
+        jnp.asarray(taus)[sel_b])
     np.testing.assert_array_equal(
         np.asarray(f_cut).reshape(b, m.Ns, m.S), np.asarray(cut.slab_cut))
 
