@@ -33,6 +33,7 @@ import numpy as np
 
 from benchmarks.bench_fleet_sync import _fleet_walk
 from benchmarks.common import city_scene, emit
+from repro.core import bitplane as bp
 from repro.core.pipeline import SessionConfig
 from repro.serve import lod_service as svc
 
@@ -100,7 +101,7 @@ def run():
                        and drain < MAX_DRAIN):
                     service.sync(walks[-1])
                     drain += 1
-                leftover = int(np.asarray(service.state.pending).sum())
+                leftover = int(np.asarray(bp.count(service.state.pending)).sum())
 
                 by = np.stack([np.asarray(s.sync_bytes) for s in rows])
                 pages = np.stack([np.asarray(s.pages) for s in rows])

@@ -124,7 +124,7 @@ def _run_deadline(tree, cfg, n_normal, n_straggler, tier, paths, deliver,
         sched.tick()
     # drain: motion the budget deferred still gets served (and counted)
     for _ in range(16):
-        if sched.tick() is None:
+        if not np.asarray(sched.tick().mtp_ms).any():   # served nobody
             break
     records = sched.recorder.drain()
     mtp = np.concatenate([r["wait_ms"] + r["service_ms"] for r in records])

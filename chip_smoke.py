@@ -164,7 +164,8 @@ def served_phase(city, tree, *, n_clients: int = 32, wave: int = 8,
         t0 = time.perf_counter()
         stats = sched.tick()
         wall_ms = (time.perf_counter() - t0) * 1e3
-        check(stats is not None, f"tick {t}: nothing was synced")
+        check(bool(np.asarray(stats.mtp_ms).any()),
+              f"tick {t}: nothing was synced")
         served = sorted(set(movers) | set(admitted))
         slots = np.array([svc._slot_of(c) for c in served])
         stale = int(np.asarray(stats.resweeps).sum())

@@ -181,9 +181,9 @@ def decode(codec: Codec, e: EncodedGaussians, sh_k: int) -> Gaussians:
 def encode_rows(codec: Codec, g: Gaussians, ids: jax.Array
                 ) -> EncodedGaussians:
     """Gather rows `ids` (-1 padded → row 0) from a Gaussian table and encode
-    them: the ONE gather + quantize/pack helper behind every wire path — the
-    single-client pipeline's unicast Δcut, the per-client reference encoder,
-    and the fleet encode-once union stream (repro.serve.delta_path)."""
+    them: the gather + quantize/pack helper of the single-client pipeline's
+    unicast Δcut and the per-client reference encoder (the fleet union
+    gathers its shipped rows itself, repro.serve.delta_path)."""
     return encode(codec, g.slice_rows(jnp.clip(ids, 0)))
 
 

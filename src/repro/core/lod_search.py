@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import bitplane as bp
 from repro.core.lod_tree import LodTree
 from repro.serve import tracing
 
@@ -79,7 +80,8 @@ class TemporalState:
     cam0: jax.Array            # (Ns, 3) camera at last sweep
     rho: jax.Array             # (Ns,)  safe radius
     parent_expand0: jax.Array  # (Ns,)  top parent-expand bit at last sweep
-    slab_cut0: jax.Array       # (Ns, S) cached cut
+    slab_cut0: jax.Array       # (Ns, words(S)) uint32 — cached cut, packed
+    #                            (`repro.core.bitplane`)
     root_expand0: jax.Array    # (Ns,)
     swept: jax.Array           # (Ns,)  ever swept
 
@@ -89,7 +91,7 @@ class TemporalState:
             cam0=jnp.zeros((Ns, 3), jnp.float32),
             rho=jnp.zeros((Ns,), jnp.float32),
             parent_expand0=jnp.zeros((Ns,), bool),
-            slab_cut0=jnp.zeros((Ns, S), bool),
+            slab_cut0=jnp.zeros((Ns, bp.words(S)), jnp.uint32),
             root_expand0=jnp.zeros((Ns,), bool),
             swept=jnp.zeros((Ns,), bool),
         )
@@ -251,7 +253,7 @@ def full_search(tree: LodTree, cam_pos: jax.Array, focal: jax.Array,
     )
     state = TemporalState(
         cam0=jnp.broadcast_to(cam_pos, (m.Ns, 3)),
-        rho=rho, parent_expand0=rpe, slab_cut0=slab_cut,
+        rho=rho, parent_expand0=rpe, slab_cut0=bp.pack(slab_cut),
         root_expand0=root_expand, swept=jnp.ones((m.Ns,), bool),
     )
     return cut, state
@@ -274,14 +276,14 @@ def temporal_search(tree: LodTree, state: TemporalState, cam_pos: jax.Array,
         tree, cam_pos, focal, tau, rpe)
 
     sel = stale[:, None]
-    slab_cut = jnp.where(sel, fresh_cut, state.slab_cut0)
+    slab_cut = jnp.where(sel, fresh_cut, bp.unpack(state.slab_cut0, m.S))
     root_expand = jnp.where(stale, fresh_root_expand, state.root_expand0)
 
     new_state = TemporalState(
         cam0=jnp.where(sel, cam_pos[None, :], state.cam0),
         rho=jnp.where(stale, fresh_rho, state.rho),
         parent_expand0=rpe,
-        slab_cut0=slab_cut,
+        slab_cut0=bp.pack(slab_cut),
         root_expand0=root_expand,
         swept=jnp.ones((m.Ns,), bool),
     )
@@ -473,8 +475,9 @@ def sweep_slab_camera_pairs(slab_mu, slab_size, slab_end, slab_is_leaf,
 def _apply_slab_updates(slab_cut, root_expand, rho, cam0, sel, f_cut, f_rexp,
                         f_rho, cam_pos):
     """In-place (donated) state update — avoids re-copying the whole slab
-    state every frame in the host-driven loop."""
-    return (slab_cut.at[sel].set(f_cut),
+    state every frame in the host-driven loop. `slab_cut` is packed; the
+    swept cuts `f_cut` are packed here."""
+    return (slab_cut.at[sel].set(bp.pack(f_cut)),
             root_expand.at[sel].set(f_rexp),
             rho.at[sel].set(f_rho),
             cam0.at[sel].set(cam_pos[None, :]))
@@ -515,8 +518,8 @@ def temporal_search_hybrid(tree: LodTree, state: TemporalState, cam_pos,
         cam0=cam0, rho=rho, parent_expand0=rpe, slab_cut0=slab_cut,
         root_expand0=root_expand, swept=jnp.ones((m.Ns,), bool))
     cut = CutResult(
-        top_cut=top_cut, slab_cut=slab_cut, root_expand=root_expand,
-        resweep=stale,
+        top_cut=top_cut, slab_cut=bp.unpack(slab_cut, m.S),
+        root_expand=root_expand, resweep=stale,
         nodes_touched=jnp.asarray(m.T + n_stale * m.S, jnp.int32))
     return cut, new_state
 

@@ -14,8 +14,10 @@ tables stay consistent — the GC-like co-design of the paper. The client
 renders its exact received cut between syncs (DESIGN.md §7: with the radial
 LoD metric the cut is orientation-free, so head rotation needs no new data).
 
-State is a dense bitmap over padded node ids (5 bytes/node on the cloud —
-~5 MB per million Gaussians), sharded with the tree on the cloud mesh.
+State is a set of bit planes over padded node ids (`repro.core.bitplane`):
+which Gaussians the client holds, its previous cut, and each node's age in
+syncs as eight planes of a saturating 8-bit counter — 12 bits per node on
+the cloud, updated with word operations.
 """
 
 from __future__ import annotations
@@ -28,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import bitplane as bp
 from repro.serve import tracing
 
 ID_BYTES = 4          # plain 32-bit ids on the wire
@@ -38,64 +41,116 @@ PAGE_HEADER_BYTES = 16  # per priority page of the paged multicast stream
 #                         (page rank, row count, first gid, checksum)
 
 
+AGE_BITS = 8
+AGE_MAX = (1 << AGE_BITS) - 1   # the age saturates here: "never, or long ago"
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class ManagerState:
-    """Cloud-side management table (the client mirrors it deterministically)."""
+    """Cloud-side management table (the client mirrors it deterministically),
+    as bit planes over the padded node ids (`repro.core.bitplane`)."""
 
-    client_has: jax.Array   # (N,) bool — which Gaussians the client stores
-    last_used: jax.Array    # (N,) int32 — sync index when last in a cut
-    cut_prev: jax.Array     # (N,) bool — previous cut (for membership deltas)
+    client_has: jax.Array   # (W,) uint32 — which Gaussians the client stores
+    age: Tuple[jax.Array, ...]  # 8 × (W,) uint32 — plane k holds bit k of
+    #                         each node's age: syncs since it was last in a
+    #                         cut, saturating at AGE_MAX (one byte a node,
+    #                         kept as eight planes so that every operation
+    #                         on it is a word operation)
+    cut_prev: jax.Array     # (W,) uint32 — previous cut (membership deltas)
 
     @staticmethod
     def initial(n: int) -> "ManagerState":
+        w = bp.words(n)
         return ManagerState(
-            client_has=jnp.zeros((n,), bool),
-            last_used=jnp.full((n,), -(2**30), jnp.int32),
-            cut_prev=jnp.zeros((n,), bool),
+            client_has=jnp.zeros((w,), jnp.uint32),
+            age=tuple(jnp.full((w,), 0xFFFFFFFF, jnp.uint32)
+                      for _ in range(AGE_BITS)),
+            cut_prev=jnp.zeros((w,), jnp.uint32),
         )
+
+
+def check_w_star(w_star: int) -> int:
+    """The reuse window the saturating age can apply exactly: a node is
+    evicted once its age passes `w_star`, and an age that saturated at
+    AGE_MAX is read as "more than AGE_MAX - 1" — exact for w_star < AGE_MAX."""
+    w_star = int(w_star)
+    if not 0 <= w_star < AGE_MAX:
+        raise ValueError(f"w_star {w_star} outside [0, {AGE_MAX}): the "
+                         f"management table's {AGE_BITS}-bit age evicts "
+                         f"exactly only below {AGE_MAX} syncs")
+    return w_star
+
+
+def age_step(age, cut: jax.Array):
+    """One sync of the age planes: 0 where `cut` is set, else one more,
+    saturating at AGE_MAX — a ripple-carry increment across the planes."""
+    carry = ~functools.reduce(jnp.bitwise_and, age)
+    out = []
+    for plane in age:
+        out.append((plane ^ carry) & ~cut)
+        carry = plane & carry
+    return tuple(out)
+
+
+def age_above(age, w_star: jax.Array) -> jax.Array:
+    """Plane of the nodes whose age exceeds `w_star` (a traced scalar in
+    [0, AGE_MAX)): a comparison from the top plane down."""
+    w = jnp.asarray(w_star, jnp.uint32)
+    gt = jnp.zeros_like(age[0])
+    eq = ~gt
+    for k in range(AGE_BITS - 1, -1, -1):
+        wk = jnp.uint32(0) - ((w >> k) & 1)     # all ones where bit k is set
+        gt = gt | (eq & age[k] & ~wk)
+        eq = eq & ~(age[k] ^ wk)
+    return gt
 
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class SyncPlan:
-    """What one LoD sync transmits (masks over node ids + byte accounting)."""
+    """What one LoD sync transmits: planes over node ids (`bitplane`) and
+    the counts the byte accounting needs."""
 
-    delta_data: jax.Array    # (N,) bool — Δcut: attribute payload to send
-    cut_add: jax.Array       # (N,) bool — ids entering the render queue
-    cut_remove: jax.Array    # (N,) bool — ids leaving the render queue
-    evicted: jax.Array       # (N,) bool — dropped by the shared reuse rule
+    cut: jax.Array           # (W,) uint32 — the sync's cut (render queue)
+    delta_data: jax.Array    # (W,) uint32 — Δcut: attribute payload to send
+    cut_add: jax.Array       # (W,) uint32 — ids entering the render queue
+    cut_remove: jax.Array    # (W,) uint32 — ids leaving the render queue
+    evicted: jax.Array       # (W,) uint32 — dropped by the shared reuse rule
     n_delta: jax.Array       # () int32
+    n_ids: jax.Array         # () int32 — cut_add + cut_remove ids
     n_resident: jax.Array    # () int32 — client occupancy after the sync
     payload_bytes: jax.Array  # () float32 — given bytes/Gaussian (see below)
 
     def wire_bytes(self, bytes_per_gaussian: float) -> jax.Array:
-        ids = (self.cut_add.sum() + self.cut_remove.sum()).astype(jnp.float32)
+        ids = self.n_ids.astype(jnp.float32)
         return (self.n_delta.astype(jnp.float32) * bytes_per_gaussian
                 + ids * ID_BYTES_DELTA + SYNC_HEADER_BYTES)
 
 
 @functools.partial(jax.jit, static_argnames=())
-def cloud_sync(state: ManagerState, cut_mask: jax.Array, t: jax.Array,
-               w_star: jax.Array) -> Tuple[ManagerState, SyncPlan]:
-    """One management-table update on the cloud (paper Fig. 9, left).
+def cloud_sync(state: ManagerState, cut: jax.Array, w_star: jax.Array
+               ) -> Tuple[ManagerState, SyncPlan]:
+    """One management-table update on the cloud (paper Fig. 9, left), over
+    words: `cut` is the sync's cut plane, w_star the shared reuse threshold
+    (in syncs, below AGE_MAX: `check_w_star`). Every operation is per word,
+    so leading (client) axes on the state and the cut batch it."""
+    delta_data = cut & ~state.client_has
+    cut_add = cut & ~state.cut_prev
+    cut_remove = state.cut_prev & ~cut
 
-    t is the sync counter; w_star the shared reuse threshold (in syncs)."""
-    delta_data = cut_mask & ~state.client_has
-    cut_add = cut_mask & ~state.cut_prev
-    cut_remove = state.cut_prev & ~cut_mask
-
-    last_used = jnp.where(cut_mask, t, state.last_used)
-    has = state.client_has | cut_mask
-    evicted = has & ((t - last_used) > w_star)
+    age = age_step(state.age, cut)
+    has = state.client_has | cut
+    evicted = has & age_above(age, w_star)
     has = has & ~evicted
 
-    new_state = ManagerState(client_has=has, last_used=last_used, cut_prev=cut_mask)
+    new_state = ManagerState(client_has=has, age=age, cut_prev=cut)
     plan = SyncPlan(
-        delta_data=delta_data, cut_add=cut_add, cut_remove=cut_remove,
-        evicted=evicted,
-        n_delta=delta_data.sum().astype(jnp.int32),
-        n_resident=has.sum().astype(jnp.int32),
+        cut=cut, delta_data=delta_data, cut_add=cut_add,
+        cut_remove=cut_remove, evicted=evicted,
+        n_delta=bp.count(delta_data),
+        n_ids=bp.count(cut_add) + bp.count(cut_remove),
+        n_resident=bp.count(has),
         payload_bytes=jnp.float32(0.0),
     )
     return new_state, plan
@@ -146,86 +201,64 @@ def gather_payload(tree_gaussians, delta_mask: jax.Array, budget: int):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnames=())
+@functools.partial(jax.jit, static_argnames=("slab_width",))
 @tracing.scoped("table.update")
-def batched_cloud_sync(states: ManagerState, cut_masks: jax.Array,
-                       ts: jax.Array, w_star: jax.Array
+def batched_cloud_sync(states: ManagerState, top_cut: jax.Array,
+                       slab_cut: jax.Array, active: jax.Array,
+                       w_star: jax.Array, *, slab_width: int
                        ) -> Tuple[ManagerState, SyncPlan]:
-    """`cloud_sync` vmapped over B clients (one table per headset, one shared
-    tree). `states` leaves are (B, N); cut_masks (B, N); ts (B,). The reuse
-    window is shared. Returns batched (ManagerState, SyncPlan) — each client's
-    slice is bit-identical to a sequential per-client `cloud_sync`."""
-    return jax.vmap(cloud_sync, in_axes=(0, 0, 0, None))(
-        states, cut_masks, ts, w_star)
+    """`cloud_sync` over B clients (one table per headset, one shared tree).
 
-
-def _pairwise_sum(x: jax.Array) -> jax.Array:
-    """Sum over the last axis by halving it: elementwise adds in one fixed
-    order whatever the leading axes' layout, so a fleet sharded over clients
-    sums each client's floats exactly as one device does (a reduce may pick
-    its order per program; on a TPU it did, per-device shape by shape)."""
-    n = x.shape[-1]
-    width = 1 << max(n - 1, 0).bit_length()
-    x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - n)])
-    while x.shape[-1] > 1:
-        half = x.shape[-1] // 2
-        x = x[..., :half] + x[..., half:]
-    return x[..., 0]
+    The cut arrives as the search leaves it: `top_cut` (B, T) bool and the
+    packed slab cuts `slab_cut` (B, Ns, words(slab_width)) uint32; it is
+    joined into one (B, W) plane per client in global node order. A slot
+    outside `active` ((B,) bool) takes an empty cut and keeps its table
+    bitwise. Each client's slice is bit-identical to a sequential
+    per-client `cloud_sync`."""
+    with tracing.scope("table.update/pack"):
+        on = bp.rows(active, 2)
+        new, plan = cloud_sync(states, bp.join(top_cut, slab_cut, slab_width)
+                               & on, w_star)
+        new = jax.tree_util.tree_map(lambda n, o: jnp.where(on, n, o),
+                                     new, states)
+    return new, plan
 
 
 def batched_wire_bytes(plan: SyncPlan, bytes_per_gaussian: float, *,
-                       shared_payload: bool = False,
-                       active=None, delivered=None,
+                       shared_rows=None, active=None,
                        client_pages=None) -> jax.Array:
     """(B,) per-client downlink bytes for a batched SyncPlan.
 
-    (`SyncPlan.wire_bytes` reduces over every axis and is only correct for the
-    unbatched case.)
+    Without `shared_rows` — the legacy unicast format: every client
+    receives its own encoded Δcut stream (payload bytes ∝ its n_delta; Δ
+    row ids are implicit, recomputable client-side from cut_add & ~has).
 
-    shared_payload=False — the legacy unicast format: every client receives
-    its own encoded Δcut stream (payload bytes ∝ its n_delta; Δ row ids are
-    implicit, recomputable client-side from cut_add & ~has).
-
-    shared_payload=True — the encode-once fleet format
+    With `shared_rows` — the encode-once fleet format
     (repro.serve.delta_path): the union Δcut is multicast ONCE as
-    [union gids + encoded rows]; clients filter the stream themselves, so the
-    only per-client traffic stays the membership ids + header. Each shared
-    row's cost (attributes + its id) is split evenly across the clients that
-    requested it, so per-client figures still sum to the fleet total:
+    [union gids + encoded rows]; clients filter the stream themselves, so
+    the only per-client traffic stays the membership ids + header. Each
+    shared row's cost (attributes + its id) is split evenly across the
+    clients that ingested it: `shared_rows` ((B,) float32,
+    `DeltaBatch.shared_rows`) is, per client, the sum over the rows it
+    ingested this sync of 1 / (clients that ingested the row). Rows a
+    tight `delta_budget` paged out of the stream cost nothing until the
+    sync that ships them. Per-client figures still sum to the fleet total:
     Σ_b bytes_b = U·(bytes_per_gaussian + ID_BYTES_DELTA) + Σ_b(ids_b·2 + hdr)
     — downlink grows with *unique* Gaussians, not with B. Crossover: a row
     with a SINGLE requester costs ID_BYTES_DELTA more than on the unicast
     path (whose Δ ids are implicit), so a fully disjoint fleet pays a small
-    id overhead; sharing by ≥2 clients is always a win.
-
-    `delivered` is an optional (B, N) bool mask of the rows each client
-    ACTUALLY ingested this sync (`DeltaBatch.delivered` from the paged
-    stream, repro.serve.delta_path). Without it the shared split charges
-    `plan.delta_data` — every requested row, INCLUDING rows a tight
-    `delta_budget` paged out of the stream; pass it so deferred rows cost
-    nothing until the sync that ships them (the silent-overcharge bug the
-    paged stream fixes). `client_pages` ((B,) int32, same source) adds the
-    per-page framing: PAGE_HEADER_BYTES for each priority page the client
-    pulled rows from.
+    id overhead; sharing by ≥2 clients is always a win. `client_pages`
+    ((B,) int32, same source) adds the per-page framing: PAGE_HEADER_BYTES
+    for each priority page the client pulled rows from.
 
     `active` is an optional (B,) bool slot mask (ragged fleets,
     repro.serve.fleet): an inactive slot receives NOTHING — not even the
-    sync header — so its row is exactly 0.0 bytes, and inactive slots are
-    excluded from the shared-row requester split."""
-    delta = plan.delta_data if delivered is None else delivered
-    if active is not None:
-        delta = delta & active[:, None]
-    ids = (plan.cut_add.sum(axis=1) + plan.cut_remove.sum(axis=1)
-           ).astype(jnp.float32)
-    base = ids * ID_BYTES_DELTA + SYNC_HEADER_BYTES
-    if not shared_payload:
+    sync header — so its row is exactly 0.0 bytes."""
+    base = plan.n_ids.astype(jnp.float32) * ID_BYTES_DELTA + SYNC_HEADER_BYTES
+    if shared_rows is None:
         out = plan.n_delta.astype(jnp.float32) * bytes_per_gaussian + base
     else:
-        share = delta.sum(axis=0)                            # (N,) requesters
-        frac = _pairwise_sum(jnp.where(delta,
-                                       1.0 / jnp.maximum(share, 1)[None, :],
-                                       0.0))
-        out = frac * (bytes_per_gaussian + ID_BYTES_DELTA) + base
+        out = shared_rows * (bytes_per_gaussian + ID_BYTES_DELTA) + base
         if client_pages is not None:
             out = out + client_pages.astype(jnp.float32) * PAGE_HEADER_BYTES
     if active is not None:

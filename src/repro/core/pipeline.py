@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import bitplane as bp
 from repro.core import compression as comp
 from repro.core import lod_search as ls
 from repro.core import manager as mgr
@@ -109,6 +110,7 @@ def session_init(tree: LodTree, cfg: SessionConfig) -> SessionState:
     """Fresh session state. The initial TemporalState has `swept=False`
     everywhere, so the first `cloud_sync_step` performs a full sweep —
     bit-identical to `ls.full_search` (no special first-frame case)."""
+    mgr.check_w_star(cfg.w_star)
     m = tree.meta
     n = tree.n_pad
     z = tree.gaussians
@@ -148,13 +150,18 @@ def cloud_sync_step(tree: LodTree, codec: comp.Codec, cfg: SessionConfig,
                                        jnp.float32(focal), jnp.float32(cfg.tau))
     mask = cut.mask(tree)
     t = state.sync_index
-    mgr_state, plan = mgr.cloud_sync(state.mgr_state, mask, t,
-                                     jnp.int32(cfg.w_star))
+    mgr_state, planes = mgr.cloud_sync(state.mgr_state, bp.pack(mask),
+                                       jnp.int32(cfg.w_star))
+    # the single client's plan as node masks: its payload and its mirror
+    n = mask.shape[0]
+    delta_data, cut_add, cut_remove = (
+        bp.unpack(p, n) for p in (planes.delta_data, planes.cut_add,
+                                  planes.cut_remove))
     # wire: Δcut payload (compressed) + cut membership deltas
     # single-client shim: the legacy unicast wire format (one per-client
     # stream, implicit Δ ids) — the fleet service dedups this per sync via
-    # repro.serve.delta_path, through the same compression.encode_rows
-    ids, n_delta = mgr.gather_payload(tree.gaussians, plan.delta_data,
+    # repro.serve.delta_path, through the same codec
+    ids, n_delta = mgr.gather_payload(tree.gaussians, delta_data,
                                       cfg.cut_budget)
     sh_k = tree.gaussians.sh.shape[1]
     if cfg.use_compression:
@@ -163,8 +170,8 @@ def cloud_sync_step(tree: LodTree, codec: comp.Codec, cfg: SessionConfig,
     else:
         dec = tree.gaussians.slice_rows(jnp.clip(ids, 0))
     # client applies the sync
-    client = mgr.client_sync(state.client, plan.delta_data, plan.cut_add,
-                             plan.cut_remove, t, jnp.int32(cfg.w_star))
+    client = mgr.client_sync(state.client, delta_data, cut_add, cut_remove,
+                             t, jnp.int32(cfg.w_star))
     client_store = _apply_payload(state.client_store, ids, dec)
     gids, count, _overflow = ls.cut_gids(cut, tree, cfg.cut_budget)
     new_state = SessionState(
@@ -175,10 +182,10 @@ def cloud_sync_step(tree: LodTree, codec: comp.Codec, cfg: SessionConfig,
         synced=jnp.asarray(True),
         cut_size=count,
         delta_size=n_delta,
-        sync_bytes=plan.wire_bytes(bytes_per_g),
+        sync_bytes=planes.wire_bytes(bytes_per_g),
         nodes_touched=cut.nodes_touched,
         resweeps=cut.resweep.sum().astype(jnp.int32),
-        client_resident=plan.n_resident)
+        client_resident=planes.n_resident)
     return new_state, stats
 
 
@@ -212,13 +219,13 @@ def _fresh_session_like(state: SessionState) -> SessionState:
     """A freshly-initialized SessionState with `state`'s leaf shapes —
     bitwise identical to `session_init` for the same tree/config, but
     jittable (shapes come from the traced state, not host objects)."""
-    n = state.mgr_state.client_has.shape[0]
-    ns, s = state.temporal.slab_cut0.shape
+    n = state.client.has.shape[0]
+    ns, sw = state.temporal.slab_cut0.shape
     store = state.client_store
     return SessionState(
         mgr_state=mgr.ManagerState.initial(n),
         client=mgr.ClientState.initial(n),
-        temporal=ls.TemporalState.initial(ns, s),
+        temporal=ls.TemporalState.initial(ns, bp.WORD * sw),
         client_store=Gaussians(
             mu=jnp.zeros_like(store.mu),
             log_scale=jnp.zeros_like(store.log_scale),

@@ -83,6 +83,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import bitplane as bp
 from repro.core import compression as comp
 from repro.core import lod_search as ls
 from repro.core import manager as mgr
@@ -112,11 +113,12 @@ class ServiceState:
     count: `fleet` (repro.serve.fleet.FleetState) records which slots hold a
     client. A fully-active fleet is exactly the legacy fixed-size service."""
 
-    mgr: mgr.ManagerState       # leaves (C, N)
-    temporal: ls.TemporalState  # leaves (C, Ns, ...)
+    mgr: mgr.ManagerState       # bit planes, leaves (C, W) and (C, 8, W)
+    temporal: ls.TemporalState  # leaves (C, Ns, ...); slab_cut0 packed
     cut_gids: jax.Array         # (C, cut_budget) int32, -1 padded
     sync_index: jax.Array       # (C,) int32 — per-slot syncs WHILE ACTIVE
-    pending: jax.Array          # (C, N) bool — Δ rows owed to the slot from
+    pending: jax.Array          # (C, W) uint32 plane (`repro.core.bitplane`)
+    #                             — Δ rows owed to the slot from
     #                             earlier paged syncs (deferred by the
     #                             stream budget / row allowance); folded
     #                             into the next sync's union as forced-stale
@@ -202,7 +204,7 @@ def service_init(tree: LodTree, cfg: SessionConfig, n_clients: int,
         temporal=ls.TemporalState.initial_batched(m.Ns, m.S, cap),
         cut_gids=jnp.full((cap, cfg.cut_budget), -1, jnp.int32),
         sync_index=jnp.zeros((cap,), jnp.int32),
-        pending=jnp.zeros((cap, tree.n_pad), bool),
+        pending=jnp.zeros((cap, bp.words(tree.n_pad)), jnp.uint32),
         fleet=flt.fleet_init(cap, n_clients),
     )
 
@@ -216,11 +218,12 @@ def _fresh_slot_leaves(state: ServiceState):
     """(fresh ManagerState, fresh TemporalState, fresh cut row, fresh sync
     counter, fresh pending row) for one slot — shapes from the traced
     state, so usable in jit."""
-    n = state.mgr.client_has.shape[1]
-    ns, s = state.temporal.slab_cut0.shape[1:]
-    return (mgr.ManagerState.initial(n), ls.TemporalState.initial(ns, s),
+    w = state.mgr.client_has.shape[1]
+    ns, sw = state.temporal.slab_cut0.shape[1:]
+    return (mgr.ManagerState.initial(bp.WORD * w),
+            ls.TemporalState.initial(ns, bp.WORD * sw),
             jnp.full((state.cut_gids.shape[1],), -1, jnp.int32), jnp.int32(0),
-            jnp.zeros((n,), bool))
+            jnp.zeros((w,), jnp.uint32))
 
 
 def _reset_slot(state: ServiceState, slot) -> ServiceState:
@@ -252,11 +255,13 @@ def service_nack_rows(state: ServiceState, slot, lost_rows) -> ServiceState:
     path): the rows fold into the next sync's union exactly like
     budget-deferred pages, so the retransmit rides the normal priority
     stream — no special wire format, and convergence-to-oracle holds under
-    loss for the same reason it holds under paging. `slot`/`lost_rows` are
-    TRACED (one trace per capacity bucket). Inactive slots are a no-op (a
-    NACK racing an eviction must not resurrect the slot's debt)."""
+    loss for the same reason it holds under paging. `lost_rows` is a (W,)
+    uint32 plane; `slot`/`lost_rows` are TRACED (one trace per capacity
+    bucket). Inactive slots are a no-op (a NACK racing an eviction must not
+    resurrect the slot's debt)."""
     slot = jnp.asarray(slot, jnp.int32)
-    row = state.pending[slot] | (lost_rows & state.fleet.active[slot])
+    row = state.pending[slot] | jnp.where(state.fleet.active[slot],
+                                          lost_rows, jnp.uint32(0))
     return dataclasses.replace(state, pending=state.pending.at[slot].set(row))
 
 
@@ -310,20 +315,56 @@ def service_shrink(state: ServiceState, perm) -> ServiceState:
     )
 
 
+def _select_bit(word: jax.Array, rank: jax.Array) -> jax.Array:
+    """Index of the `rank`-th (0-based) set bit of each uint32 `word`: a
+    binary search over halves by their popcounts."""
+    pos = jnp.zeros_like(rank)
+    for width in (16, 8, 4, 2, 1):
+        low = jax.lax.population_count(
+            word & jnp.uint32((1 << width) - 1)).astype(jnp.int32)
+        up = rank >= low
+        rank = jnp.where(up, rank - low, rank)
+        pos = jnp.where(up, pos + width, pos)
+        word = jnp.where(up, word >> width, word)
+    return pos
+
+
+def _cut_queue(plane: jax.Array, budget: int):
+    """One cut plane (W,) compacted to its first `budget` node ids in
+    ascending order (-1 padded), and the cut's size: a popcount per word,
+    an inclusive prefix sum over the words, the word that holds each queue
+    position (a histogram of the prefix sums, summed again), then the
+    position's bit within its word."""
+    pc = jax.lax.population_count(plane).astype(jnp.int32)
+    incl = jnp.cumsum(pc)
+    count = incl[-1]
+    hist = jnp.zeros((budget,), jnp.int32).at[incl].add(1, mode="drop")
+    word = jnp.minimum(jnp.cumsum(hist), plane.shape[0] - 1)
+    k = jnp.arange(budget, dtype=jnp.int32)
+    pos = _select_bit(plane[word], k - (incl - pc)[word])
+    return jnp.where(k < count, word * bp.WORD + pos, -1), count
+
+
 @functools.partial(jax.jit, static_argnames=("budget", "mesh"))
 @tracing.scoped("table.update")
 def _batched_cut_gids(masks: jax.Array, budget: int, mesh=None):
-    def one(m):
-        (g,) = jnp.nonzero(m, size=budget, fill_value=-1)
-        return g.astype(jnp.int32), m.sum().astype(jnp.int32)
-    gids, counts = jax.vmap(one)(masks)
+    """Every slot's cut plane ((C, W) uint32) as its render queue: (C,
+    budget) int32 node ids, ascending, -1 padded, and (C,) cut sizes — in
+    chunks of slots (`bitplane.map_slots`)."""
+    with tracing.scope("table.update/pack"):
+        masks = shd.constrain_fleet(masks, ("clients", None), mesh)
+        one = jax.vmap(functools.partial(_cut_queue, budget=budget))
+        _, (gids, counts) = bp.map_slots(
+            lambda _, m: (None, one(m)), masks,
+            bp.slot_chunk(masks.shape[0], 4 * budget))
     gids = shd.constrain_fleet(gids, ("clients", None), mesh)
     counts = shd.constrain_fleet(counts, ("clients",), mesh)
     return gids, counts
 
 
 def _finish_sync(tree: LodTree, cfg: SessionConfig, state: ServiceState,
-                 temporal: ls.TemporalState, masks: jax.Array,
+                 temporal: ls.TemporalState, top_cut: jax.Array,
+                 slab_cut: jax.Array,
                  nodes_touched: jax.Array, resweeps: jax.Array,
                  bytes_per_g: float, codec: Optional[comp.Codec] = None,
                  dedup: bool = False, delta_budget: Optional[int] = None,
@@ -334,6 +375,10 @@ def _finish_sync(tree: LodTree, cfg: SessionConfig, state: ServiceState,
                                      Optional[dp.DeltaBatch]]:
     """Shared tail of both sync paths: batched management-table update,
     per-client render queues, the encode-once Δcut payload, and accounting.
+    The cut arrives as the search leaves it — `top_cut` (C, T) bool and the
+    packed slab cuts `slab_cut` (C, Ns, words(S)) — and the tail works on
+    bit planes from there on (`repro.core.bitplane`): no per-node byte
+    array of the whole fleet is built.
 
     With `dedup`, the wire format is the shared multicast stream of
     repro.serve.delta_path (one codec call on the fleet union; `sync_bytes`
@@ -383,11 +428,10 @@ def _finish_sync(tree: LodTree, cfg: SessionConfig, state: ServiceState,
     else:
         eff = active & shd.constrain_fleet(
             jnp.asarray(participate, bool), ("clients",), mesh)
-    masks = masks & eff[:, None]
-    new_mgr, plan = mgr.batched_cloud_sync(state.mgr, masks, state.sync_index,
-                                           jnp.int32(cfg.w_star))
-    new_mgr = flt.freeze_inactive(new_mgr, state.mgr, eff)
-    gids, counts = _batched_cut_gids(masks, cfg.cut_budget, mesh=mesh)
+    new_mgr, plan = mgr.batched_cloud_sync(state.mgr, top_cut, slab_cut, eff,
+                                           jnp.int32(cfg.w_star),
+                                           slab_width=tree.meta.S)
+    gids, counts = _batched_cut_gids(plan.cut, cfg.cut_budget, mesh=mesh)
     if participate is not None:
         # a non-participating slot KEEPS its render queue (an inactive one's
         # stored queue is already the fresh -1 row, so this is a no-op for
@@ -408,23 +452,22 @@ def _finish_sync(tree: LodTree, cfg: SessionConfig, state: ServiceState,
                                      allowance=allowance, page_size=page_size,
                                      widths=widths)
         sync_bytes = mgr.batched_wire_bytes(plan, bytes_per_g,
-                                            shared_payload=True,
+                                            shared_rows=batch.shared_rows,
                                             active=eff,
-                                            delivered=batch.delivered,
                                             client_pages=batch.client_pages)
         saved = unicast - sync_bytes
         delta_overflow = batch.client_overflow
-        delta_shipped = batch.delivered.sum(axis=1).astype(jnp.int32)
+        delta_shipped = bp.count(batch.delivered)
         # carry-over debt: deferred rows survive until they ship — unless
         # the shared reuse rule evicted them meanwhile (the oracle's client
         # would have dropped them too)
-        pending = batch.deferred & ~plan.evicted & eff[:, None]
+        pending = batch.deferred & ~plan.evicted & bp.rows(eff, 2)
         if participate is not None:
             # a slot that sat the tick out keeps its debt untouched (its
             # rows were masked out of this union, so `deferred` is blank
             # for it — wiping would silently lose its owed pages)
             pending = jnp.where(eff[:, None], pending, state.pending)
-        delta_deferred = pending.sum(axis=1).astype(jnp.int32)
+        delta_deferred = bp.count(pending)
         pages = batch.client_pages
     else:
         sync_bytes = unicast
@@ -604,8 +647,10 @@ def service_sync_vmapped(tree: LodTree, cfg: SessionConfig,
     cut, temporal = ls.batched_temporal_search(
         tree, state.temporal, cams, jnp.float32(focal), tau_b)
     temporal = flt.freeze_inactive(temporal, state.temporal, eff)
-    masks = ls.batched_cut_mask(cut, tree)
-    return _finish_sync(tree, cfg, state, temporal, masks,
+    # an eff slot's packed slab cut is its fresh one; any other slot's cut
+    # is masked out of the tail
+    return _finish_sync(tree, cfg, state, temporal, cut.top_cut,
+                        temporal.slab_cut0,
                         cut.nodes_touched, cut.resweep.sum(axis=1),
                         bytes_per_g, codec=codec, dedup=dedup,
                         delta_budget=delta_budget, priority=priority,
@@ -622,6 +667,9 @@ def _apply_pooled_updates(slab_cut, root_expand, rho, cam0, sel_b, sel_s,
                           guard: bool = False, mesh=None):
     """Scatter pooled sweep results back into the batched temporal state.
     Repeat-padded (client, slab) pairs write identical values — harmless.
+    `slab_cut` is packed; the sweep's cuts `f_cut` arrive as (K, S) bool
+    from a bucket of one lane chunk and are packed here, or packed already
+    from a chunked bucket (`_pooled_pair_sweep`).
 
     `guard` (static; only the sharded per-shard compaction sets it): a
     client shard with ZERO stale pairs pads its bucket lanes with a
@@ -630,6 +678,9 @@ def _apply_pooled_updates(slab_cut, root_expand, rho, cam0, sel_b, sel_s,
     shard's bucket is provably a no-op. The meshless global pool never pads
     with non-stale pairs (count > 0 is guaranteed), so the unguarded program
     is byte-identical to the pre-mesh service."""
+    if f_cut.dtype == jnp.bool_:
+        with tracing.scope("table.update/pack"):
+            f_cut = bp.pack(f_cut)
     if guard:
         f_cut = jnp.where(valid[:, None], f_cut, slab_cut[sel_b, sel_s])
         f_rexp = jnp.where(valid, f_rexp, root_expand[sel_b, sel_s])
@@ -701,26 +752,16 @@ def _compact_stale_pairs(stale: jax.Array, bucket: int, n_shards: int = 1,
     return sel_b, sel_s, valid
 
 
-@functools.partial(jax.jit, static_argnames=("impl", "mesh"))
-@tracing.scoped("lod.pair_sweep")
-def _pooled_pair_sweep(tables: ls.SlabTables, rpe, cams, taus, sel_b, sel_s,
-                       focal, *, impl: str, mesh=None):
-    """Gather the pooled pairs' slab attributes (means, sizes, DFS subtree
-    ends, leaf and valid flags) from the device-resident tables and sweep
-    them — ONE fused program (the gathers never detour through the host).
-    `impl` picks the vmapped XLA sweep (`lod_search.sweep_slab_camera_pairs`)
-    or the Pallas lod-cut kernel (`repro.kernels.lod_cut.lod_pair_sweep_pallas`,
-    compiled on a TPU and interpreted on the CPU): twins that compute the
-    same prefix max over subtree ends, bitwise equal on the cut, and both
-    checked against the level-loop oracle (`repro.kernels.ref`).
+# lanes of one pass of the pooled sweep: a larger bucket is swept in chunks
+# of this many lanes, in a loop inside the program, so the sweep's
+# temporaries stay those of one chunk whatever the pool
+SWEEP_CHUNK = 1 << 16
 
-    Sharded fleets: the pair axis is constrained onto the `clients` axis
-    (each shard's bucket lanes sweep on that shard); the slab-table gathers
-    cross the `slabs` axis, where the partitioner inserts the collectives —
-    the XLA sweep partitions cleanly. The Pallas kernel is a single opaque
-    dispatch the partitioner cannot split, so under a mesh its pair inputs
-    are explicitly REPLICATED first (correct but not scaled — prefer
-    impl='xla' on a mesh)."""
+
+def _sweep_lanes(tables: ls.SlabTables, rpe, cams, taus, sel_b, sel_s, focal,
+                 impl: str, mesh):
+    """Gather and sweep one set of pair lanes: (in_cut (K, S) bool,
+    root_expand (K,), rho (K,))."""
     if impl == "pallas":
         with tracing.scope("lod.pair_sweep/gather"):
             tau_sel = taus[sel_b]
@@ -741,6 +782,52 @@ def _pooled_pair_sweep(tables: ls.SlabTables, rpe, cams, taus, sel_b, sel_s,
             for g in gathered)
         tau_sel = shd.constrain_fleet(tau_sel, ("clients",), mesh)
     return ls.sweep_slab_camera_pairs(*gathered, focal, tau_sel)
+
+
+@functools.partial(jax.jit, static_argnames=("impl", "chunk", "mesh"))
+@tracing.scoped("lod.pair_sweep")
+def _pooled_pair_sweep(tables: ls.SlabTables, rpe, cams, taus, sel_b, sel_s,
+                       focal, *, impl: str, chunk: int = SWEEP_CHUNK,
+                       mesh=None):
+    """Gather the pooled pairs' slab attributes (means, sizes, DFS subtree
+    ends, leaf and valid flags) from the device-resident tables and sweep
+    them — ONE fused program (the gathers never detour through the host).
+    `impl` picks the vmapped XLA sweep (`lod_search.sweep_slab_camera_pairs`)
+    or the Pallas lod-cut kernel (`repro.kernels.lod_cut.lod_pair_sweep_pallas`,
+    compiled on a TPU and interpreted on the CPU): twins that compute the
+    same prefix max over subtree ends, bitwise equal on the cut, and both
+    checked against the level-loop oracle (`repro.kernels.ref`).
+
+    A bucket of at most `chunk` lanes is swept in one pass and returns its
+    cuts as (K, S) bool. A larger one is swept `chunk` lanes at a time in a
+    loop (the last pass repeat-padded), each pass packing its cuts, and
+    returns them as (K, words(S)) uint32: the temporaries are one pass's
+    whatever the pool.
+
+    Sharded fleets: the pair axis is constrained onto the `clients` axis
+    (each shard's bucket lanes sweep on that shard); the slab-table gathers
+    cross the `slabs` axis, where the partitioner inserts the collectives —
+    the XLA sweep partitions cleanly. The Pallas kernel is a single opaque
+    dispatch the partitioner cannot split, so under a mesh its pair inputs
+    are explicitly REPLICATED first (correct but not scaled — prefer
+    impl='xla' on a mesh)."""
+    k = sel_b.shape[0]
+    if k <= chunk:
+        return _sweep_lanes(tables, rpe, cams, taus, sel_b, sel_s, focal,
+                            impl, mesh)
+    passes = -(-k // chunk)
+    pad = passes * chunk - k
+    lanes = (jnp.concatenate([sel_b, sel_b[:pad]]),
+             jnp.concatenate([sel_s, sel_s[:pad]]))
+
+    def one_pass(_, lane):
+        with tracing.scope("lod.pair_sweep/chunk"):
+            cut, rexp, rho = _sweep_lanes(tables, rpe, cams, taus, *lane,
+                                          focal, impl, mesh)
+            return None, (bp.pack(cut), rexp, rho)
+
+    _, out = bp.map_slots(one_pass, lanes, chunk)
+    return tuple(x[:k] for x in out)
 
 
 def service_sync_pooled(tree: LodTree, cfg: SessionConfig,
@@ -802,7 +889,8 @@ def service_sync_pooled(tree: LodTree, cfg: SessionConfig,
     `account`, a dict when given, receives what the sync decided: `n_stale`
     (the stale-pair pool, the host count read above), `lanes` (the pair
     lanes the bucket swept: bucket × client shards, 0 when nothing was
-    stale) and `stale_causes` (the (3,) int32 device counts of
+    stale), `sweep_chunks` (the sweep's passes of at most `SWEEP_CHUNK`
+    lanes) and `stale_causes` (the (3,) int32 device counts of
     `lod_search.stale_causes`, never read here).
 
     NOTE: like `temporal_search_hybrid`, the scatter donates the incoming
@@ -843,7 +931,7 @@ def service_sync_pooled(tree: LodTree, cfg: SessionConfig,
     tp = state.temporal
     slab_cut, root_expand, rho, cam0 = (tp.slab_cut0, tp.root_expand0,
                                         tp.rho, tp.cam0)
-    bucket = 0
+    bucket = chunks = 0
     if n_stale > 0:
         if k > 1:
             bucket = ls.pow2_bucket(int(shard_counts.max()), n_pairs // k)
@@ -854,13 +942,14 @@ def service_sync_pooled(tree: LodTree, cfg: SessionConfig,
         f_cut, f_rexp, f_rho = _pooled_pair_sweep(
             tables, rpe, cams, tau_b, sel_b, sel_s, jnp.float32(focal),
             impl=sweep_impl, mesh=mesh)
+        chunks = -(-bucket * k // SWEEP_CHUNK)
         slab_cut, root_expand, rho, cam0 = _apply_pooled_updates(
             slab_cut, root_expand, rho, cam0, sel_b, sel_s,
             f_cut, f_rexp, f_rho, cams[sel_b], valid, guard=k > 1,
             mesh=mesh)
     if account is not None:
         account.update(n_stale=n_stale, lanes=bucket * k,
-                       stale_causes=causes)
+                       sweep_chunks=chunks, stale_causes=causes)
 
     # the eff-masked scatter never touches a non-participating slot's
     # donated buffers; freeze the two non-donated leaves the same way so
@@ -872,11 +961,8 @@ def service_sync_pooled(tree: LodTree, cfg: SessionConfig,
         slab_cut0=slab_cut, root_expand0=root_expand,
         swept=jnp.where(eff[:, None], True, tp.swept))
     nodes_touched = m.T + stale.sum(axis=1).astype(jnp.int32) * m.S
-    cut = ls.CutResult(top_cut=top_cut, slab_cut=slab_cut,
-                       root_expand=root_expand, resweep=stale,
-                       nodes_touched=nodes_touched)
-    masks = ls.batched_cut_mask(cut, tree)
-    return _finish_sync(tree, cfg, state, temporal, masks, nodes_touched,
+    return _finish_sync(tree, cfg, state, temporal, top_cut, slab_cut,
+                        nodes_touched,
                         stale.sum(axis=1), bytes_per_g, codec=codec,
                         dedup=dedup, delta_budget=delta_budget,
                         priority=priority, allowance=allowance,
@@ -997,6 +1083,7 @@ class LodService:
         if sweep_impl == "pallas" and mode != "pooled":
             raise ValueError("sweep_impl='pallas' drives the pooled bucket "
                              "sweep; use mode='pooled'")
+        mgr.check_w_star(cfg.w_star)
         self.tree = tree
         self.cfg = cfg
         # the serving mesh (explicit, else the ambient use_fleet_mesh one):
@@ -1062,7 +1149,10 @@ class LodService:
                     f"delta_budget)")
             self.page_size = int(page_size)
         # coarse-first priority key of the paged union stream, derived once
-        self._priority = tree.node_levels()
+        # over the bit planes' node axis (padding nodes never join a union)
+        levels = tree.node_levels()
+        self._priority = jnp.pad(
+            levels, (0, bp.WORD * bp.words(tree.n_pad) - levels.shape[0]))
         # closed-loop bitrate controller state (host-side, like `taus`):
         # per-slot byte target (inf = uncontrolled), row allowance
         # (-1 sentinel = uncontrolled) and foveation fallback scale
@@ -1104,7 +1194,9 @@ class LodService:
         # syncs run so far: the `tick` of every trace span of the next sync
         self.syncs = 0
         # what the last pooled sync decided (`service_sync_pooled`'s
-        # `account`); empty after a vmapped sync, which pools nothing
+        # `account`) and the state bytes per slot it left
+        # (`slot_state_bytes`); empty after a vmapped sync, which pools
+        # nothing
         self.last_account: dict = {}
         self._rcfg_cache = {}
         self._stack_cache = {}
@@ -1357,6 +1449,7 @@ class LodService:
                 ref_mask=_remap_rows(self.last_delta.ref_mask),
                 delivered=_remap_rows(self.last_delta.delivered),
                 deferred=_remap_rows(self.last_delta.deferred),
+                shared_rows=_remap_rows(self.last_delta.shared_rows),
                 client_overflow=_remap_rows(self.last_delta.client_overflow),
                 client_pages=_remap_rows(self.last_delta.client_pages))
         self._delta_ids = self._delta_ids[perm]
@@ -1526,6 +1619,7 @@ class LodService:
                 self.tree, self.cfg, self.state, self._slot_cams, self.focal,
                 self.bytes_per_g, tables=self.tables,
                 sweep_impl=self.sweep_impl, account=self.last_account, **kw)
+            self.last_account["slot_state_bytes"] = self._slot_state_bytes()
         else:
             self.state, stats, batch = service_sync_vmapped(
                 self.tree, self.cfg, self.state, self._slot_cams, self.focal,
@@ -1628,7 +1722,7 @@ class LodService:
         mask[g] = True
         self.state = shd.shard_service_state(
             self.mesh, service_nack_rows(self.state, slot,
-                                         jnp.asarray(mask)))
+                                         bp.pack(jnp.asarray(mask))))
         return int(mask.sum())
 
     def nack(self, client_id: int, lost_pages) -> int:
@@ -1728,3 +1822,16 @@ class LodService:
                 self._rcfg_cache[static_sig] = rcfg
         return service_render_step(self.tree, self.state, rigs, rcfg,
                                    path=path, mesh=self.mesh)
+
+
+def empty_stats(capacity: int) -> ServiceStats:
+    """The stats of a tick that served no slot: every row zero."""
+    zi = jnp.zeros((capacity,), jnp.int32)
+    zf = jnp.zeros((capacity,), jnp.float32)
+    zb = jnp.zeros((capacity,), bool)
+    return ServiceStats(
+        cut_size=zi, delta_size=zi, unique_delta=zi, sync_bytes=zf,
+        dedup_bytes_saved=zf, nodes_touched=zi, resweeps=zi,
+        client_resident=zi, overflow=zb, delta_overflow=zb,
+        delta_shipped=zi, delta_deferred=zi, pages=zi, mtp_ms=zf,
+        deadline_miss=zb)

@@ -66,10 +66,10 @@ from repro.core.lod_tree import LodTree
 from repro.core.pipeline import SessionConfig
 from repro.serve import fleet as flt
 from repro.serve.lod_service import (AdmissionDenied, LodService,
-                                     ServiceStats)
+                                     ServiceStats, empty_stats)
 from repro.sharding import fleet as shd
 
-SNAPSHOT_FORMAT = "nebula-fleet-snapshot/1"
+SNAPSHOT_FORMAT = "nebula-fleet-snapshot/2"   # /2: bit-plane slot state
 JOURNAL_NAME = "journal.jsonl"
 SNAPSHOT_DIRNAME = "snapshots"
 
@@ -196,16 +196,9 @@ def snapshot_service(service: LodService, directory: str, step: int = 0, *,
 def _zero_stats(capacity: int, sync_bytes: np.ndarray) -> ServiceStats:
     """A `ServiceStats` carrying only the restored per-slot wire bytes —
     the single column the rate controller's feedback loop reads."""
-    zi = jnp.zeros((capacity,), jnp.int32)
-    zf = jnp.zeros((capacity,), jnp.float32)
-    zb = jnp.zeros((capacity,), bool)
-    return ServiceStats(
-        cut_size=zi, delta_size=zi, unique_delta=zi,
-        sync_bytes=jnp.asarray(sync_bytes, jnp.float32),
-        dedup_bytes_saved=zf, nodes_touched=zi, resweeps=zi,
-        client_resident=zi, overflow=zb, delta_overflow=zb,
-        delta_shipped=zi, delta_deferred=zi, pages=zi,
-        mtp_ms=zf, deadline_miss=zb)
+    return dataclasses.replace(empty_stats(capacity),
+                               sync_bytes=jnp.asarray(sync_bytes,
+                                                      jnp.float32))
 
 
 def _read_extras(directory: str, step: int) -> Dict[str, Any]:

@@ -72,7 +72,7 @@ import numpy as np
 from repro.core import lod_search as ls
 from repro.serve import tracing
 from repro.serve.lod_service import (AdmissionDenied, LodService,
-                                     ServiceStats)
+                                     ServiceStats, empty_stats)
 
 DEFAULT_DEADLINE_MS = 33.0  # ~30 Hz pose-to-update budget
 
@@ -321,18 +321,20 @@ class DeadlineScheduler:
             spent += marginal
         return selected
 
-    def tick(self, now: Optional[float] = None) -> Optional[ServiceStats]:
+    def tick(self, now: Optional[float] = None) -> ServiceStats:
         """Run one scheduler tick: select, partial-sync, time, refit the
         cost model, stamp MTP columns, add the tick to `recorder`. Returns
-        the stamped per-slot stats, or None when no client had unserved
-        motion (nothing to do — an idle fleet costs nothing)."""
+        the stamped per-slot stats; when no client had unserved motion the
+        tick syncs nothing, records nothing and returns all-zero stats
+        (`lod_service.empty_stats`: no slot served — an idle fleet costs
+        nothing)."""
         svc = self.service
         index = svc.syncs
         with tracing.span("sched.tick", tick=index):
             with tracing.span("sched.select"):
                 selected = self.select(now)
             if not selected:
-                return None
+                return empty_stats(svc.capacity)
             cams = {cid: self._clients[cid].pending_cam for cid in selected}
             t0 = self._clock()
             stats = svc.sync(cams, participate=selected)

@@ -66,6 +66,19 @@ STAGES = (
     "delta.union",
 )
 
+# finer scopes inside two stages; the TPU trace the benchmark's tests keep
+# (tests/bench/data/tpu_trace_pruned.json) was recorded before them, so
+# they stand apart from STAGES
+DETAIL_STAGES = (
+    # inside `table.update`: the word-level table update over bit planes,
+    # the packing of swept slab cuts, the first-owner counts and the
+    # compaction of cut planes into render queues
+    "table.update/pack",
+    # inside `lod.pair_sweep`: one pass of a bucket swept in lane chunks
+    # (buckets wider than `lod_service.SWEEP_CHUNK` only)
+    "lod.pair_sweep/chunk",
+)
+
 SPAN_PREFIX = "nebula."
 
 READ_SPANS = ("sched.preview_read", "svc.rate_read", "svc.stale_count_read",
@@ -79,7 +92,8 @@ def scope(stage: str):
     """`jax.named_scope` of a device stage, for use inside jitted code. A
     child stage (`parent/child`) is opened inside its parent's scope and
     names only its own part."""
-    assert stage in STAGES, f"unknown stage {stage!r} (see tracing.STAGES)"
+    assert stage in STAGES + DETAIL_STAGES, \
+        f"unknown stage {stage!r} (see tracing.STAGES)"
     return jax.named_scope(stage.rsplit("/", 1)[-1])
 
 

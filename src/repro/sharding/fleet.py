@@ -4,8 +4,10 @@ cloud LoD sync path (ROADMAP "shard ServiceState + tree on the cloud mesh").
 The serving mesh has two logical axes:
 
   clients — shards every per-slot leaf of the service on its leading SLOT
-            axis (`ServiceState` / `FleetState` / `ServiceStats` /
-            per-client cut queues / fallback frames). A host owns a
+            axis (`ServiceState` with its bit planes, (C, W) and (C, Ns,
+            S/32) uint32 / `FleetState` / `ServiceStats` / per-client cut
+            queues / fallback frames); a plane's word axis is never split,
+            so a slot's words stay on one device. A host owns a
             contiguous block of slots: its staleness pool, management
             tables, Δ ref-mask rows, and wire accounting all live where its
             clients live.

@@ -9,6 +9,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from repro.core import bitplane as bp
 from repro.core import compression as comp
 from repro.core.pipeline import SessionConfig, session_wire_format
 from repro.serve import delta_path as dp
@@ -16,6 +17,16 @@ from repro.serve import lod_service as svc
 
 FOCAL = 1400.0
 TAU = 32.0
+
+
+def _planes(masks) -> jax.Array:
+    """(B, N) bool masks as the bit planes the union takes."""
+    return bp.pack(jnp.asarray(masks))
+
+
+def _nodes(planes, n=None) -> np.ndarray:
+    """Bit planes of a batch or a service as (..., n) bool node masks."""
+    return np.asarray(bp.unpack(planes, n))
 
 
 def _masks_for_overlap(n: int, b: int, overlap: float, rng,
@@ -53,11 +64,11 @@ def test_dedup_decode_bitwise_matches_per_client(small_tree, overlap):
     budget = int(masks.any(axis=0).sum()) + 32
 
     batch = dp.build_delta_batch(small_tree.gaussians, codec,
-                                 jnp.asarray(masks), budget)
+                                 _planes(masks), budget)
     assert not bool(batch.overflow)
     assert int(batch.n_union) == int(masks.any(axis=0).sum())
     assert int(batch.n_shipped) == int(batch.n_union)  # ample: no paging
-    assert not np.asarray(batch.deferred).any()
+    assert not _nodes(batch.deferred).any()
     ref = dp.encode_per_client(small_tree.gaussians, codec,
                                jnp.asarray(masks), budget)
 
@@ -95,14 +106,15 @@ def test_all_clients_idle_sync(small_tree):
     well-formed batch."""
     codec, _ = session_wire_format(small_tree, SessionConfig(tau=TAU))
     masks = jnp.zeros((4, small_tree.n_pad), bool)
-    batch = dp.build_delta_batch(small_tree.gaussians, codec, masks, 64)
+    batch = dp.build_delta_batch(small_tree.gaussians, codec, _planes(masks),
+                                 64)
     assert int(batch.n_union) == 0
     assert not bool(batch.overflow)
     assert not np.asarray(batch.ref_mask).any()
     ids, _dec = dp.decode_client(codec, batch,
                                  small_tree.gaussians.sh.shape[1], 2)
     assert (np.asarray(ids) == -1).all()
-    assert np.asarray(dp.first_owner_counts(masks)).sum() == 0
+    assert np.asarray(dp.first_owner_counts(_planes(masks))).sum() == 0
 
 
 def test_union_overflow_flagged(small_tree):
@@ -111,14 +123,14 @@ def test_union_overflow_flagged(small_tree):
                                sizes=(100, 80))
     codec, _ = session_wire_format(small_tree, SessionConfig(tau=TAU))
     batch = dp.build_delta_batch(small_tree.gaussians, codec,
-                                 jnp.asarray(masks), 64)
+                                 _planes(masks), 64)
     assert bool(batch.overflow)
     # ... but nothing is lost: exactly budget rows shipped, the rest is
     # reported as per-client deferred carry-over
     assert int(batch.n_shipped) == 64
     assert int(batch.n_union) == 180
-    delivered = np.asarray(batch.delivered)
-    deferred = np.asarray(batch.deferred)
+    delivered = _nodes(batch.delivered, small_tree.n_pad)
+    deferred = _nodes(batch.deferred, small_tree.n_pad)
     np.testing.assert_array_equal(delivered | deferred, masks)
     assert not (delivered & deferred).any()
     assert deferred.any(axis=1).all()  # both clients lost rows to paging
@@ -133,7 +145,7 @@ def test_paged_stream_ships_coarse_rows_first(small_tree):
     codec, _ = session_wire_format(small_tree, SessionConfig(tau=TAU))
     prio = np.asarray(small_tree.node_levels())
     batch = dp.build_delta_batch(small_tree.gaussians, codec,
-                                 jnp.asarray(masks), 128,
+                                 _planes(masks), 128,
                                  priority=small_tree.node_levels())
     union = masks.any(axis=0)
     gids = np.asarray(batch.union_gids)
@@ -159,7 +171,7 @@ def test_union_ranking_compiles_once_across_stream_widths(small_tree):
     for budget in (64, 256, 1024):
         masks = _masks_for_overlap(small_tree.n_pad, 3, 0.5, rng)
         batch = dp.build_delta_batch(small_tree.gaussians, codec,
-                                     jnp.asarray(masks), budget,
+                                     _planes(masks), budget,
                                      priority=small_tree.node_levels())
         widths.add(batch.union_gids.shape[0])
         traced = traced or dp._rank_union._cache_size()
@@ -181,7 +193,7 @@ def test_built_widths_only_pad_the_stream(small_tree, budget, widths, page):
     unpadded stream's, and the width is the narrowest built width within
     the budget that holds the union's pow2 bucket, else that bucket."""
     rng = np.random.default_rng(13)
-    masks = jnp.asarray(_masks_for_overlap(small_tree.n_pad, 3, 0.5, rng))
+    masks = _planes(_masks_for_overlap(small_tree.n_pad, 3, 0.5, rng))
     codec, _ = session_wire_format(small_tree, SessionConfig(tau=TAU))
     sh_k = small_tree.gaussians.sh.shape[1]
     kw = dict(priority=small_tree.node_levels(), page_size=page)
@@ -220,7 +232,7 @@ def test_built_widths_only_pad_the_stream(small_tree, budget, widths, page):
 def test_first_owner_counts_partition_union(small_tree):
     rng = np.random.default_rng(5)
     masks = _masks_for_overlap(small_tree.n_pad, 4, 0.5, rng)
-    u = np.asarray(dp.first_owner_counts(jnp.asarray(masks)))
+    u = np.asarray(dp.first_owner_counts(_planes(masks)))
     assert u.sum() == masks.any(axis=0).sum()
     assert (u <= masks.sum(axis=1)).all()
 
@@ -255,7 +267,7 @@ def test_service_encodes_once_per_sync(small_tree, monkeypatch):
     service.sync(cams + 1.0)
     assert calls["n"] == 2  # still one per sync, B-independent
 
-    masks = np.asarray(service.state.mgr.cut_prev)
+    masks = _nodes(service.state.mgr.cut_prev, small_tree.n_pad)
     calls["n"] = 0
     dp.encode_per_client(small_tree.gaussians, service.codec,
                          jnp.asarray(masks), 256)
@@ -307,7 +319,7 @@ def test_service_surfaces_delta_overflow(small_tree):
     tight = svc.LodService(small_tree, cfg, 2, focal=FOCAL, dedup=True,
                            delta_budget=64)
     st = tight.sync(cams)
-    deferred = np.asarray(tight.last_delta.deferred).any(axis=1)
+    deferred = _nodes(tight.last_delta.deferred).any(axis=1)
     np.testing.assert_array_equal(np.asarray(st.delta_overflow), deferred)
     assert deferred.all()  # both clients' Δs dwarf 64 rows here
     assert bool(tight.last_delta.overflow)
@@ -333,7 +345,7 @@ def test_tight_budget_bytes_charge_only_shipped_rows(small_tree):
                            delta_budget=64, page_size=16)
     st = tight.sync(cams)
     batch = tight.last_delta
-    delivered = np.asarray(batch.delivered)
+    delivered = _nodes(batch.delivered)
     share = delivered.sum(axis=0)
     ids = np.asarray(st.cut_size)  # first sync: cut_add == cut, no removes
     want = np.empty(2)
@@ -361,7 +373,7 @@ def _converge(service, cams, oracle_delivered, budget):
     got = np.zeros_like(oracle_delivered)
     for k in range(max_syncs):
         service.sync(cams)
-        got |= np.asarray(service.last_delta.delivered)
+        got |= _nodes(service.last_delta.delivered)
         if not np.asarray(service.state.pending).any():
             break
     assert not np.asarray(service.state.pending).any(), \
@@ -383,7 +395,7 @@ def test_paged_syncs_converge_bitwise_to_unbudgeted_oracle(small_tree, mode,
     kw = dict(focal=FOCAL, mode=mode, sweep_impl=impl, dedup=True)
     base = svc.LodService(small_tree, cfg, 3, **kw)
     base.sync(cams)
-    oracle = np.asarray(base.last_delta.delivered)
+    oracle = _nodes(base.last_delta.delivered)
     assert not np.asarray(base.state.pending).any()  # ample: no debt, ever
 
     budget = 128
@@ -687,7 +699,7 @@ def test_lost_row_mask_is_clients_refs_in_lost_pages(small_tree):
         # a NACK for every page is exactly this sync's delivered set
         all_mask = dp.lost_row_mask(batch, slot, range(n_pages))
         np.testing.assert_array_equal(
-            all_mask, np.asarray(batch.delivered)[slot],
+            all_mask, _nodes(batch.delivered)[slot],
             err_msg=f"slot{slot}:all")
 
 

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import bitplane as bp
 from repro.core import lod_search as ls
 from repro.core import manager as mgr
 from repro.core.camera import StereoRig, make_camera
@@ -345,7 +346,8 @@ def test_service_dedup_client_payload_roundtrip(small_tree):
     walks = _client_walks(rng, b, 3)
     cfg = SessionConfig(tau=TAU, cut_budget=8192)
     service = svc.LodService(small_tree, cfg, b, focal=FOCAL, dedup=True)
-    prev_has = np.asarray(service.state.mgr.client_has).copy()
+    n = small_tree.n_pad
+    prev_has = np.asarray(bp.unpack(service.state.mgr.client_has, n))
     for f in range(3):
         stats = service.sync(walks[f])
         for i in range(b):
@@ -357,7 +359,7 @@ def test_service_dedup_client_payload_roundtrip(small_tree):
             want = np.where(cut & ~prev_has[i])[0]
             np.testing.assert_array_equal(got, want, err_msg=f"{f}/{i}")
             assert int(stats.delta_size[i]) == len(want)
-        prev_has = np.asarray(service.state.mgr.client_has).copy()
+        prev_has = np.asarray(bp.unpack(service.state.mgr.client_has, n))
 
 
 # -- (c) functional session core ≡ legacy CollaborativeSession ----------------

@@ -211,7 +211,11 @@ def test_tick_serves_only_unserved_motion_and_stamps_mtp(tiny_tree):
     others = [service._slot_of(1), service._slot_of(3)]
     assert (mtp[served] > 0.0).all() and not mtp[others].any()
     assert not np.asarray(stats.deadline_miss).any()
-    assert sched.tick() is None           # motion served — an idle tick
+    syncs = service.syncs
+    idle = sched.tick()                   # motion served — an idle tick
+    assert service.syncs == syncs         # syncs nothing, serves nobody
+    assert not any(np.asarray(leaf).any()
+                   for leaf in jax.tree_util.tree_leaves(idle))
     # a deadline the clock cannot hold stamps a miss for that client only
     sched.set_deadline(0, 1e-6)
     sched.observe_motion(0, [21.0, 21.0, 3.0])
